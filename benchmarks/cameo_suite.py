@@ -1140,9 +1140,8 @@ def _update_bench_stream_json(rows):
     only durable record of how ingest throughput moved across machines and
     runtime configurations, so each is stamped with the wall-clock time,
     the jax backend it ran on, and the XLA runtime flags in effect —
-    without those, a pts/s swing from flipping
-    ``--xla_cpu_use_thunk_runtime`` is indistinguishable from a code
-    regression when reading the ledger."""
+    without those, a pts/s swing from a changed XLA flag is
+    indistinguishable from a code regression when reading the ledger."""
     import os
     from datetime import datetime, timezone
 

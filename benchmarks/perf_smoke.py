@@ -60,14 +60,7 @@ import json
 import os
 import sys
 
-# Same CPU-runtime selection as benchmarks/run.py: the gated ingest
-# throughput must measure the configuration the bench ships, and the flag
-# only takes effect before the first jax computation.
-if "--xla_cpu_use_thunk_runtime" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_cpu_use_thunk_runtime=false").strip()
-
-import jax  # noqa: E402
+import jax
 import numpy as np
 
 jax.config.update("jax_enable_x64", True)  # float64 store fixture

@@ -9,24 +9,15 @@ under benchmarks/results/ for EXPERIMENTS.md.
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 import time
 
-# The rounds-mode while_loop body compiles to hundreds of small CPU
-# kernels, so per-op dispatch dominates wall time; XLA's legacy CPU
-# runtime dispatches them ~40% faster than the thunk runtime on this
-# shape of program.  Must land in the environment before the first jax
-# computation initializes the backend; a user-provided XLA_FLAGS wins.
-if "--xla_cpu_use_thunk_runtime" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_cpu_use_thunk_runtime=false").strip()
-
-import jax  # noqa: E402
+import jax
 
 jax.config.update("jax_enable_x64", True)  # CAMEO math in f64, like the paper
 
 from benchmarks import anomaly, cameo_suite, forecast, roofline  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 BENCHES = {
     "fig6": cameo_suite.bench_fig6_line_simplification,
@@ -57,6 +48,7 @@ def main() -> None:
     ap.add_argument("--only", type=str, default=None,
                     help="comma-separated bench names")
     args = ap.parse_args()
+    enable_compile_cache()
     names = args.only.split(",") if args.only else list(BENCHES)
     t0 = time.time()
     failures = []

@@ -20,6 +20,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ref as _ref
+
 
 class Aggregates(NamedTuple):
     """Per-lag ACF aggregates (Eq. 7). Each field has shape ``[L]``.
@@ -49,8 +51,8 @@ def extract_aggregates(x: jax.Array, L: int,
     """
     from repro.kernels.ops import lag_dot  # deferred: kernels sit below core
     n = x.shape[0]
-    csum = jnp.cumsum(x)
-    csum2 = jnp.cumsum(x * x)
+    csum = _ref.cumsum(x)
+    csum2 = _ref.cumsum(x * x)
     total, total2 = csum[-1], csum2[-1]
     l = jnp.arange(1, L + 1)
     # head sums: prefix up to index n-1-l.
@@ -74,8 +76,8 @@ def extract_aggregates_masked(x: jax.Array, L: int, n_valid,
     intended to be traced inside a caller's jit.
     """
     from repro.kernels.ops import lag_dot  # deferred: kernels sit below core
-    csum = jnp.cumsum(x)
-    csum2 = jnp.cumsum(x * x)
+    csum = _ref.cumsum(x)
+    csum2 = _ref.cumsum(x * x)
     total, total2 = csum[-1], csum2[-1]
     l = jnp.arange(1, L + 1)
     sx = csum[n_valid - 1 - l]

@@ -68,9 +68,9 @@ def apply_delta_dense(agg, y_old: jax.Array, delta: jax.Array, ny=None,
     L = agg[0].shape[-1]
     l = jnp.arange(1, L + 1)
 
-    cd = jnp.cumsum(delta)
+    cd = _ref.cumsum(delta)
     e = delta * (2.0 * y_old + delta)
-    ce = jnp.cumsum(e)
+    ce = _ref.cumsum(e)
     dtot, etot = cd[-1], ce[-1]
 
     dsx = cd[ny - 1 - l]
@@ -85,8 +85,8 @@ def apply_delta_dense(agg, y_old: jax.Array, delta: jax.Array, ny=None,
     # tests/test_contractions.py): XLA's CPU emitter runs both the [nyb, L]
     # shift-basis gather and a per-lag chain of 2L small dots an order of
     # magnitude slower than one batched roll+mask+reduce (the gather takes
-    # the slow general-gather path; the dot chain is dispatch-bound on the
-    # legacy runtime).  Elsewhere the gathered basis keeps the whole term at
+    # the slow general-gather path; the dot chain is dispatch-bound).
+    # Elsewhere the gathered basis keeps the whole term at
     # two matvecs against a [nyb, L] operand — matmul-shaped for the MXU.
     if form == "auto":
         form = "roll" if jax.default_backend() == "cpu" else "gather"

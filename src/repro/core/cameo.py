@@ -92,9 +92,10 @@ class CameoConfig:
     target_cr: Optional[float] = None   # minimize D s.t. CR >= target_cr
     max_cr: Optional[float] = None      # optional halt once CR reaches this
     dtype: str = "float64"
-    # -- impact-engine backend (see kernels/ops.py):
-    #    "pallas" (TPU kernels; interpret mode off-TPU) | "reference"
-    #    (pure-jnp) | "auto" (pallas on TPU, reference elsewhere)
+    # -- impact-engine backend (see kernels/ops.py for the dispatch rule):
+    #    "pallas" (TPU kernels; interpret mode off-TPU; raises where a
+    #    kernel cannot run, e.g. float64 operands on a TPU) | "reference"
+    #    (pure-jnp) | "auto" (pallas on TPU where the kernel can run)
     backend: str = "auto"
 
     def jdtype(self):
@@ -457,9 +458,8 @@ def _round_fns(cfg: CameoConfig, nb: int, n_valid: jax.Array,
                     dwin_k, start_k, _ = segment_deltas(
                         xr, prev, nxt, sel_idx, W)
                     dyw_k, ystart_k = _ops.x_window_to_y(cfg, dwin_k, start_k)
-                    if _ops._kernel_eligible(
-                            cfg.backend, cfg.stat, cfg.measure) \
-                            and not _ops.interpret_mode():
+                    if _ops.use_fused_kernel(
+                            cfg.backend, dt, cfg.stat, cfg.measure):
                         # Fused greedy kernel (real TPU): one VMEM pass walks
                         # the rank order, committing every candidate whose
                         # trial deviation on the exact running reconstruction
@@ -504,7 +504,7 @@ def _round_fns(cfg: CameoConfig, nb: int, n_valid: jax.Array,
                         gi = jnp.where(ok, g, inf)
                         order = jnp.argsort(gi)
                         gs = gi[order]
-                        csum = jnp.cumsum(
+                        csum = _ref.cumsum(
                             jnp.where(jnp.isfinite(gs), gs,
                                       jnp.asarray(0.0, dt)))
                         pred = dev + csum
@@ -661,6 +661,14 @@ def _rounds_init(xp: jax.Array, n_valid: jax.Array, cfg: CameoConfig):
     xp = jnp.where(validm, xp.astype(dt), jnp.asarray(0.0, dt))
     ny_valid = n_valid // cfg.kappa
     y0 = aggregate_series(xp, cfg.kappa)
+    # The target series is carried about its mean.  Eq. 2 does not change
+    # under a constant shift, while raw moments of a series far from zero
+    # lose the ACF to cancellation in ``m * sxx - sx * sxl``: with float64
+    # emulated in float32 pairs (TPU) the carried deviation drifted 4e-9
+    # from a fresh float64 measurement on electricity-demand data.
+    yvalid = jnp.arange(y0.shape[0]) < ny_valid
+    mu = jnp.sum(jnp.where(yvalid, y0, 0.0)) / ny_valid.astype(dt)
+    y0 = jnp.where(yvalid, y0 - mu, jnp.asarray(0.0, dt))
     agg0 = extract_aggregates_masked(y0, cfg.lags, ny_valid,
                                      backend=cfg.backend)
     tbl0 = _ops.agg_to_table(agg0)

@@ -48,6 +48,7 @@ from repro.core.cameo import (
     compress_rounds,
 )
 from repro.kernels import ops as _ops
+from repro.kernels import ref as _ref
 
 
 # ---------------------------------------------------------------------------
@@ -65,8 +66,8 @@ def chunk_agg_contrib(y_c, halo_r, off, ny: int, L: int,
     """
     m = y_c.shape[0]
     l = jnp.arange(1, L + 1)
-    csum = jnp.cumsum(y_c)
-    csum2 = jnp.cumsum(y_c * y_c)
+    csum = _ref.cumsum(y_c)
+    csum2 = _ref.cumsum(y_c * y_c)
     total, total2 = csum[-1], csum2[-1]
 
     # head: sum of y_c[t] with off+t <= ny-1-l
@@ -96,7 +97,7 @@ def chunk_delta_contrib(y_c, d_c, halo_y, halo_d, off, ny: int, L: int,
     m = y_c.shape[0]
     l = jnp.arange(1, L + 1)
     e = d_c * (2.0 * y_c + d_c)
-    cd, ce = jnp.cumsum(d_c), jnp.cumsum(e)
+    cd, ce = _ref.cumsum(d_c), _ref.cumsum(e)
     dtot, etot = cd[-1], ce[-1]
 
     hi = (ny - 1 - off) - l
@@ -184,6 +185,8 @@ def compress_partitioned(x: jax.Array, cfg: CameoConfig, T: int) -> CompressResu
         return jax.tree.map(lambda a: a.sum(0), contribs)
 
     yp0 = jax.vmap(lambda c: aggregate_series(c, kap))(xp)
+    # moments about the mean, as in cameo._rounds_init
+    yp0 = yp0 - jnp.sum(jnp.sum(yp0, axis=1)) / ny
     agg0 = global_agg_from(yp0)
     p0 = transform(acf_from_aggregates(agg0, ny))
 
@@ -282,6 +285,8 @@ def compress_partitioned_shardmap(x, cfg: CameoConfig, mesh, axis: str = "data")
         x_c = x_c.astype(dt)
         off_y = jax.lax.axis_index(axis).astype(jnp.int32) * my
         y0 = aggregate_series(x_c, kap)
+        # moments about the mean, as in compress_partitioned
+        y0 = y0 - jax.lax.psum(jnp.sum(y0), axis) / ny
         agg0 = jax.tree.map(
             lambda a: jax.lax.psum(a, axis),
             chunk_agg_contrib(y0, right_halo(y0, L), off_y, ny, L,

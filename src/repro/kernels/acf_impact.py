@@ -2,17 +2,11 @@
 
 For every candidate point i, computes the deviation measure between the
 hypothetical ACF after a single-point delta at i (Eq. 8) and the original
-ACF.  This is O(n·L) work with O(1) state per lag — VPU-shaped: the L-loop
-runs sequentially in-kernel while each step is a [1, B] vector op over the
-candidate block, and the five per-lag aggregates live in SMEM-like scalar
-reads from a VMEM-resident [5, L] table.
-
-Tiling: the candidate axis is blocked (B a multiple of 128 lanes); the padded
-series (n + 2L, zero halos) stays fully VMEM-resident — for the paper's
-workloads (n <= ~1M, f32) that is <= 4 MB of the ~16 MB VMEM budget.  The
-lag-shifted reads y[i±l] then become cheap dynamic slices instead of
-gathers.  Out-of-range lag reads land in the zero halo and are nulled by the
-head/tail masks (same masking as the reference math).
+ACF.  A single-point delta is a windowed delta of width one, so this runs
+the Eq. 9 kernel body of ``acf_window_impact`` with ``W = 1``: candidates on
+the lanes, lags on the sublanes, each candidate's ``2L + 1`` context
+gathered by XLA.  The candidates are processed in chunks (``lax.map``) so
+the gathered contexts never exceed ``block · (2L + 1)`` values.
 """
 from __future__ import annotations
 
@@ -20,105 +14,43 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-
-def _measure_init(measure: str, B: int, dtype):
-    if measure in ("mae", "rmse"):
-        return jnp.zeros((1, B), dtype)
-    if measure == "cheb":
-        return jnp.zeros((1, B), dtype)
-    raise ValueError(f"kernel supports mae/rmse/cheb, got {measure!r}")
-
-
-def _measure_update(measure: str, acc, diff):
-    if measure == "mae":
-        return acc + jnp.abs(diff)
-    if measure == "rmse":
-        return acc + diff * diff
-    return jnp.maximum(acc, jnp.abs(diff))
-
-
-def _measure_final(measure: str, acc, L: int):
-    if measure == "mae":
-        return acc / L
-    if measure == "rmse":
-        return jnp.sqrt(acc / L)
-    return acc
-
-
-def acf_impact_kernel(y_pad_ref, d_ref, agg_ref, p0_ref, out_ref,
-                      *, n: int, L: int, B: int, measure: str):
-    """One grid step: impacts for candidate block [pid*B, (pid+1)*B)."""
-    pid = pl.program_id(0)
-    s = pid * B
-    dtype = y_pad_ref.dtype
-
-    idx = s + jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)     # [1, B]
-    d = d_ref[...].reshape(1, B)
-    # y at the candidate positions (offset L in the padded series)
-    yi = y_pad_ref[pl.dslice(s + L, B)].reshape(1, B)
-    e = d * (2.0 * yi + d)
-    valid = (idx >= 0) & (idx <= n - 1)
-
-    def lag_body(lag, acc):
-        lm1 = lag - 1
-        y_f = y_pad_ref[pl.dslice(s + L + lag, B)].reshape(1, B)
-        y_b = y_pad_ref[pl.dslice(s + L - lag, B)].reshape(1, B)
-        head = ((idx <= n - 1 - lag) & valid).astype(dtype)
-        tail = ((idx >= lag) & valid).astype(dtype)
-
-        sx = agg_ref[0, lm1] + d * head
-        sxl = agg_ref[1, lm1] + d * tail
-        sx2 = agg_ref[2, lm1] + e * head
-        sxl2 = agg_ref[3, lm1] + e * tail
-        sxx = agg_ref[4, lm1] + d * (y_f * head + y_b * tail)
-
-        m = (n - lag).astype(dtype)
-        num = m * sxx - sx * sxl
-        den2 = (m * sx2 - sx * sx) * (m * sxl2 - sxl * sxl)
-        tiny = jnp.asarray(1e-30, dtype)
-        col = jnp.where(den2 > tiny,
-                        num * jax.lax.rsqrt(jnp.maximum(den2, tiny)),
-                        jnp.zeros_like(num))
-        return _measure_update(measure, acc, col - p0_ref[lm1])
-
-    acc = jax.lax.fori_loop(1, L + 1, lag_body,
-                            _measure_init(measure, B, dtype))
-    out_ref[...] = _measure_final(measure, acc, L).reshape(B)
+from repro.kernels.acf_window_impact import (_pad8, lane_blocks, moment_table,
+                                             transposed_operands,
+                                             window_impact_call)
 
 
 @functools.partial(
     jax.jit, static_argnames=("L", "measure", "block", "interpret"))
-def acf_impact_pallas(y, dval, agg_table, p0, *, L: int, measure: str = "mae",
-                      block: int = 1024, interpret: bool = False):
-    """Impacts [n] via the Pallas kernel.
+def acf_impact_pallas(y, dval, agg_table, p0, *, idx=None, L: int,
+                      measure: str = "mae", block: int = 1024,
+                      interpret: bool = False):
+    """Impacts [P] via the Pallas kernel.
 
     ``agg_table`` is the stacked [5, L] aggregate table
-    (sx, sxl, sx2, sxl2, sxx); ``p0`` the original ACF [L].
+    (sx, sxl, sx2, sxl2, sxx); ``p0`` the original ACF [L].  Candidate ``p``
+    adds ``dval[p]`` at position ``idx[p]`` of ``y`` (default: every
+    position, ``idx = arange(n)``).
     """
-    n = y.shape[0]
+    ny = y.shape[0]
     dtype = y.dtype
-    B = block
-    pad = (-n) % B
-    npad = n + pad
-    y_pad = jnp.pad(y, (L, L + pad))          # zero halos both sides
-    d_pad = jnp.pad(dval, (0, pad))
+    if idx is None:
+        idx = jnp.arange(ny, dtype=jnp.int32)
+    P = idx.shape[0]
+    Lp = _pad8(L)
+    C = lane_blocks(min(block, P))
+    nch = -(-P // C)
+    ii = jnp.pad(idx.astype(jnp.int32), (0, nch * C - P)).reshape(nch, C)
+    dd = jnp.pad(dval.astype(dtype), (0, nch * C - P)).reshape(nch, C)
+    y_pad = jnp.pad(y, (Lp, Lp + 1))
+    k = jnp.arange(2 * Lp + 1)
+    tab = moment_table(agg_table.astype(dtype), p0, Lp)
 
-    grid = (npad // B,)
-    kernel = functools.partial(
-        acf_impact_kernel, n=n, L=L, B=B, measure=measure)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(y_pad.shape, lambda i: (0,)),       # full series
-            pl.BlockSpec((B,), lambda i: (i,)),              # delta block
-            pl.BlockSpec(agg_table.shape, lambda i: (0, 0)),  # aggregates
-            pl.BlockSpec(p0.shape, lambda i: (0,)),          # original ACF
-        ],
-        out_specs=pl.BlockSpec((B,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((npad,), dtype),
-        interpret=interpret,
-    )(y_pad, d_pad, agg_table, p0)
-    return out[:n]
+    def chunk(args):
+        i, d = args
+        ctx = y_pad[jnp.clip(i, 0, ny)[:, None] + k[None, :]]
+        cT, cR, dT, s = transposed_operands(ctx, d[:, None], i, Lp)
+        return window_impact_call(cT, cR, dT, s, tab, ny, W=1, L=L,
+                                  measure=measure, interpret=interpret)
+
+    return jax.lax.map(chunk, (ii, dd)).reshape(-1)[:P]

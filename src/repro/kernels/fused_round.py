@@ -16,12 +16,13 @@ exact deviation after applying candidates ``0..j`` — in one fused pass:
   from the exclusive cumulative delta rows.  O(K·(W + L)·L) total, no
   sequential dependence beyond two cumsums.
 
-* pallas backend — a single kernel pass (`grid=(1,)`) holding the running
+* pallas backend — a single kernel pass holding the running
   reconstruction ``z = y + D`` in VMEM scratch; each rank step reads its
-  ``W + 2L`` context via dynamic slices, updates the five aggregates and the
-  scratch in place, and emits that prefix's deviation.  This is the fused
-  form of Eq. 9 ranking + Eq. 10/11 maintenance the TPU path runs natively
-  (interpret mode elsewhere, as with the other kernels in this package).
+  ``W + 2L`` context (a dynamic row offset plus one lane rotation), updates
+  the five aggregates and the scratch in place, and emits that prefix's
+  deviation.  This is the fused form of Eq. 9 ranking + Eq. 10/11
+  maintenance the TPU path runs natively (interpret mode elsewhere, as with
+  the other kernels in this package).
 
 Both forms are exact for every ``kappa`` (the ``z``-context accounts for
 boundary-bin sharing between segments mapped onto the aggregate series) and
@@ -35,7 +36,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import acf_window_impact as _awi
 from repro.kernels import ref as _ref
 
 
@@ -66,8 +69,8 @@ def _moment_deltas(d, ctx, ystarts, ny, *, L: int, form: str = "auto"):
     e = d * (2.0 * z_at + d)
     # head keeps abs_t <= ny-1-l  <=>  j < ny - l - s   (contiguous prefix);
     # tail keeps abs_t >= l       <=>  j >= l - s       (contiguous suffix).
-    cdz = jnp.pad(jnp.cumsum(d, axis=1), ((0, 0), (1, 0)))
-    cez = jnp.pad(jnp.cumsum(e, axis=1), ((0, 0), (1, 0)))
+    cdz = jnp.pad(_ref.cumsum(d, axis=1), ((0, 0), (1, 0)))
+    cez = jnp.pad(_ref.cumsum(e, axis=1), ((0, 0), (1, 0)))
     c_head = jnp.clip(ny - l[None, :] - ystarts[:, None], 0, Wy)
     c_tail = jnp.clip(l[None, :] - ystarts[:, None], 0, Wy)
     dsx = jnp.take_along_axis(cdz, c_head, axis=1)
@@ -82,8 +85,8 @@ def _moment_deltas(d, ctx, ystarts, ny, *, L: int, form: str = "auto"):
     # (TPU).  XLA's CPU emitter however runs that gather an order of
     # magnitude slower than contiguous reads (measured ~8ms/window on the
     # stream bench), and the historical L-unrolled static-slice chain is
-    # dispatch-bound on the legacy runtime (~1.5us per emitted op, 2L+ ops
-    # per call) — so on CPU the lag axis is one *batched* roll-and-reduce:
+    # dispatch-bound (2L+ emitted ops per call) — so on CPU the lag axis is
+    # one *batched* roll-and-reduce:
     # a single vmapped op the emitter fuses into one [L, K, Wy] pass.  All
     # forms are pinned against each other by `tests/test_contractions.py`.
     d_pad = jnp.pad(d, ((0, 0), (0, L)))
@@ -168,11 +171,9 @@ def window_acf_rows(y, dyws, ystarts, agg_table, ny, *, L: int):
 
 def window_rows(cfg, y, dyws, ystarts, agg_table, ny, *, L: int):
     """Backend-dispatched tier-impact rows: the Pallas kernel on a real TPU,
-    the einsum contraction elsewhere (same eligibility rule as
-    :func:`prefix_devs`)."""
+    the einsum contraction elsewhere (``ops.use_fused_kernel``)."""
     from repro.kernels import ops as _ops
-    if _ops._kernel_eligible(cfg.backend, cfg.stat, cfg.measure) \
-            and not _ops.interpret_mode():
+    if _ops.use_fused_kernel(cfg.backend, y.dtype, cfg.stat, cfg.measure):
         return window_rows_pallas(y, dyws, ystarts, agg_table, ny, L=L)
     return window_acf_rows(y, dyws, ystarts, agg_table, ny, L=L)
 
@@ -197,7 +198,7 @@ def prefix_moment_rows(y, dyws, ystarts, ok, ny, *, L: int):
     place = jax.vmap(
         lambda dr, s: jax.lax.dynamic_update_slice(
             jnp.zeros((nyb + Wy,), dt), dr, (s,))[:nyb])(d, starts)
-    d_ex = jnp.cumsum(place, axis=0) - place
+    d_ex = _ref.cumsum(place, axis=0) - place
 
     # Per-candidate context of the running reconstruction z = y + D_{<j}.
     kk = jnp.arange(Wy + 2 * L)
@@ -214,7 +215,7 @@ def prefix_acf_rows_ref(y, dyws, ystarts, ok, agg_table, ny, *, L: int):
     (see :func:`prefix_moment_rows` for the argument contract)."""
     dt = y.dtype
     dagg = prefix_moment_rows(y, dyws, ystarts, ok, ny, L=L)
-    cum = jnp.cumsum(dagg, axis=0) + agg_table[None]
+    cum = _ref.cumsum(dagg, axis=0) + agg_table[None]
     l = jnp.arange(1, L + 1)
     m = (ny - l).astype(dt)[None, :]
     return _ref.acf_from_moments(cum[:, 0], cum[:, 1], cum[:, 2],
@@ -225,51 +226,15 @@ def prefix_acf_rows_ref(y, dyws, ystarts, ok, agg_table, ny, *, L: int):
 # pallas form: independent per-candidate Eq. 9 rows
 # ---------------------------------------------------------------------------
 
-def _window_rows_kernel(dy_ref, s_ref, y_pad_ref, agg_ref, ny_ref, out_ref,
-                        *, K: int, Wy: int, L: int):
-    """Per-candidate trial ACF rows ``[K, L]`` — the kernel twin of
-    :func:`window_acf_rows` (tier-impact ranking).  Candidates are
-    independent, so each grid-free ``k`` step reads its ``Wy + 2L`` context
-    straight from the padded series and never mutates shared state."""
-    dtype = y_pad_ref.dtype
-    ny = ny_ref[0]
-    tiny = jnp.asarray(1e-30, dtype)
-
-    def step(k, _):
-        s = s_ref[k]
-        d = dy_ref[k, :].reshape(1, Wy)
-        idx = s + jax.lax.broadcasted_iota(jnp.int32, (1, Wy), 1)
-        jj = jax.lax.broadcasted_iota(jnp.int32, (1, Wy), 1)
-        z_at = y_pad_ref[pl.dslice(s + L, Wy)].reshape(1, Wy)
-        e = d * (2.0 * z_at + d)
-
-        def lag_body(lag, row):
-            lm1 = lag - 1
-            z_f = y_pad_ref[pl.dslice(s + L + lag, Wy)].reshape(1, Wy)
-            z_b = y_pad_ref[pl.dslice(s + L - lag, Wy)].reshape(1, Wy)
-            head = (idx <= ny - 1 - lag).astype(dtype)
-            tail = (idx >= lag).astype(dtype)
-            d_f = jnp.where(jj + lag < Wy, jnp.roll(d, -lag, axis=1), 0.0)
-            sx = agg_ref[0, lm1] + jnp.sum(d * head)
-            sxl = agg_ref[1, lm1] + jnp.sum(d * tail)
-            sx2 = agg_ref[2, lm1] + jnp.sum(e * head)
-            sxl2 = agg_ref[3, lm1] + jnp.sum(e * tail)
-            sxx = agg_ref[4, lm1] + jnp.sum(
-                d * (z_f * head + z_b * tail + d_f * head))
-            m = (ny - lag).astype(dtype)
-            num = m * sxx - sx * sxl
-            den2 = (m * sx2 - sx * sx) * (m * sxl2 - sxl * sxl)
-            den = jnp.sqrt(jnp.maximum(den2, tiny))
-            rho = jnp.where(den2 > tiny, num / den, jnp.zeros_like(num))
-            return jax.lax.dynamic_update_slice(
-                row, rho.reshape(1, 1), (0, lm1))
-
-        row = jax.lax.fori_loop(
-            1, L + 1, lag_body, jnp.zeros((1, L), dtype))
-        out_ref[pl.dslice(k, 1), :] = row
-        return 0
-
-    jax.lax.fori_loop(0, K, step, 0)
+def _window_rows_kernel(cT_ref, cR_ref, dT_ref, s_ref, tab_ref, ny_ref,
+                        out_ref, *, Wy: int, Lp: int):
+    """Per-candidate trial ACF rows for one 128-candidate lane block — the
+    kernel twin of :func:`window_acf_rows` (tier-impact ranking), on the
+    Eq. 9 body shared with ``acf_window_impact``."""
+    ny = ny_ref[...]
+    moments = _awi.window_moments(cT_ref, cR_ref, dT_ref, s_ref, ny,
+                                  W=Wy, Lp=Lp)
+    out_ref[...] = _awi.acf_rows(tab_ref[...], moments, ny, Lp)
 
 
 @functools.partial(jax.jit, static_argnames=("L", "interpret"))
@@ -281,100 +246,135 @@ def window_rows_pallas(y, dyws, ystarts, agg_table, ny, *, L: int,
     K, Wy = dyws.shape
     nyb = y.shape[0]
     dtype = y.dtype
-    y_pad = jnp.pad(y, (L, L + Wy))
+    Lp = _awi._pad8(L)
     starts = jnp.clip(ystarts, 0, nyb - 1).astype(jnp.int32)
-    ny_arr = jnp.asarray(ny, jnp.int32).reshape(1)
-
-    kernel = functools.partial(_window_rows_kernel, K=K, Wy=Wy, L=L)
-    return pl.pallas_call(
+    kk = jnp.arange(Wy + 2 * Lp)
+    ctx = jnp.pad(y, (Lp, Lp + Wy))[starts[:, None] + kk[None, :]]
+    cT, cR, dT, s = _awi.transposed_operands(ctx, dyws.astype(dtype), starts,
+                                             Lp)
+    Hc, Kp = cT.shape
+    kernel = functools.partial(_window_rows_kernel, Wy=Wy, Lp=Lp)
+    rows = pl.pallas_call(
         kernel,
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec(dyws.shape, lambda i: (0, 0)),
-            pl.BlockSpec(starts.shape, lambda i: (0,)),
-            pl.BlockSpec(y_pad.shape, lambda i: (0,)),
-            pl.BlockSpec(agg_table.shape, lambda i: (0, 0)),
-            pl.BlockSpec(ny_arr.shape, lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((K, L), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((K, L), dtype),
+        grid=(Kp // _awi.LANES,),
+        in_specs=_awi.window_specs(Hc, dT.shape[0], Lp),
+        out_specs=pl.BlockSpec((Lp, _awi.LANES),
+                               lambda i: (_awi._zero(), i)),
+        out_shape=jax.ShapeDtypeStruct((Lp, Kp), dtype),
         interpret=interpret,
-    )(dyws.astype(dtype), starts, y_pad, agg_table, ny_arr)
+    )(cT, cR, dT, s, _awi.moment_table(agg_table.astype(dtype), None, Lp),
+      _awi.lane_scalar(ny))
+    return rows[:L, :K].T
 
 
 # ---------------------------------------------------------------------------
 # pallas form: one fused pass with the running reconstruction in VMEM
 # ---------------------------------------------------------------------------
 
-def _prefix_scan_kernel(dy_ref, s_ref, ok_ref, y_pad_ref, agg_ref, p0_ref,
-                        ny_ref, eps_ref, out_ref, z_ref,
-                        *, K: int, Wy: int, L: int, measure: str,
-                        greedy: bool):
-    dtype = y_pad_ref.dtype
-    z_ref[...] = y_pad_ref[...]
+def _prefix_scan_kernel(s_ref, ok_ref, ny_ref, eps_ref, y_ref, d_ref,
+                        tab_ref, out_ref, z_ref, *, K: int, L: int, Lp: int,
+                        NR: int, measure: str, greedy: bool):
+    """Walk the K candidates in rank order against the running
+    reconstruction ``z`` (VMEM scratch, rows of 128 lanes, position ``q``
+    at ``z[(q + Lp) // 128, (q + Lp) % 128]``).
+
+    Candidate ``k`` reads the ``NR`` rows holding its ``Wcp``-lane context
+    (dynamic row offset), aligns them with one dynamic lane rotation, and
+    builds every lag-shifted view it needs with *strided* rotations: row
+    ``r`` of ``roll(broadcast(v), 1, stride=1)`` is ``v`` shifted by lag
+    ``r + 1``.  The backward products read ``z[t - l]`` that way; the
+    forward ones are rewritten over the shifted *delta*
+    (``sum_t d_t z_{t+l} = sum_u d_{u-l} z_u``), so no view needs a
+    backward stride.  A committed delta is rotated back and added to the
+    same rows.
+    """
+    dtype = z_ref.dtype
+    LANES = _awi.LANES
+    Wcp = d_ref.shape[1]
+    Wn = NR * LANES
+    z_ref[...] = y_ref[...]
     ny = ny_ref[0]
     eps = eps_ref[0]
-    tiny = jnp.asarray(1e-30, dtype)
+    tab = tab_ref[...]
+    lag = jax.lax.broadcasted_iota(jnp.int32, (Lp, Wcp), 0) + 1
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, Wcp), 1)
+    out_lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    zero = jnp.zeros((Lp, Wcp), dtype)
+    pad_n = jnp.zeros((1, Wn - Wcp), dtype)
+
+    def shifted(v):
+        return pltpu.roll(jnp.broadcast_to(v, (Lp, Wcp)), jnp.int32(1), 1,
+                          stride=1, stride_axis=0)
 
     def step(k, agg5):
         s = s_ref[k]
-        d = dy_ref[k, :].reshape(1, Wy) * ok_ref[k]
-        idx = s + jax.lax.broadcasted_iota(jnp.int32, (1, Wy), 1)
-        jj = jax.lax.broadcasted_iota(jnp.int32, (1, Wy), 1)
-        z_at = z_ref[pl.dslice(s + L, Wy)].reshape(1, Wy)
-        e = d * (2.0 * z_at + d)
+        # (lax.div/rem: jnp's floor-division lowering does not compile
+        # in a kernel under x64; s >= 0, so truncation is floor)
+        r0 = jax.lax.div(s, jnp.int32(LANES))
+        c0 = jax.lax.rem(s, jnp.int32(LANES))
+        rows = [z_ref[pl.ds(r0 + i, 1), :] for i in range(NR)]
+        w = pltpu.roll(jnp.concatenate(rows, axis=1), jax.lax.rem(Wn - c0, jnp.int32(Wn)),
+                       1)[:, :Wcp]                   # w[m] = z[s - Lp + m]
+        d = d_ref[pl.ds(k, 1), :]                    # delta at lanes Lp + j
+        pos = s - Lp + lane
+        e = d * (2.0 * w + d)
+        head = pos + lag <= ny - 1
+        tail = pos >= lag
+        fwd = pos <= ny - 1
 
-        def lag_body(lag, carry):
-            trial5, acc = carry
-            lm1 = lag - 1
-            z_f = z_ref[pl.dslice(s + L + lag, Wy)].reshape(1, Wy)
-            z_b = z_ref[pl.dslice(s + L - lag, Wy)].reshape(1, Wy)
-            head = (idx <= ny - 1 - lag).astype(dtype)
-            tail = (idx >= lag).astype(dtype)
-            d_f = jnp.where(jj + lag < Wy, jnp.roll(d, -lag, axis=1), 0.0)
-            sx = trial5[0, lm1] + jnp.sum(d * head)
-            sxl = trial5[1, lm1] + jnp.sum(d * tail)
-            sx2 = trial5[2, lm1] + jnp.sum(e * head)
-            sxl2 = trial5[3, lm1] + jnp.sum(e * tail)
-            sxx = trial5[4, lm1] + jnp.sum(
-                d * (z_f * head + z_b * tail + d_f * head))
-            col = jnp.stack([sx, sxl, sx2, sxl2, sxx])
-            trial5 = jax.lax.dynamic_update_slice(
-                trial5, col[:, None], (0, lm1))
-            m = (ny - lag).astype(dtype)
-            num = m * sxx - sx * sxl
-            den2 = (m * sx2 - sx * sx) * (m * sxl2 - sxl * sxl)
-            den = jnp.sqrt(jnp.maximum(den2, tiny))
-            rho = jnp.where(den2 > tiny, num / den, jnp.zeros_like(num))
-            diff = rho - p0_ref[lm1]
-            if measure == "mae":
-                acc = acc + jnp.abs(diff)
-            elif measure == "rmse":
-                acc = acc + diff * diff
-            else:                                            # cheb
-                acc = jnp.maximum(acc, jnp.abs(diff))
-            return trial5, acc
+        def lane_sum(x):
+            return jnp.sum(x, axis=1, keepdims=True)
 
-        trial5, acc = jax.lax.fori_loop(
-            1, L + 1, lag_body, (agg5, jnp.asarray(0.0, dtype)))
-        if measure == "mae":
-            dev = acc / L
-        elif measure == "rmse":
-            dev = jnp.sqrt(acc / L)
-        else:
-            dev = acc
-        out_ref[pl.dslice(k, 1)] = dev.reshape(1)
+        trial = (
+            agg5[0] + lane_sum(jnp.where(head, d, zero)),
+            agg5[1] + lane_sum(jnp.where(tail, d, zero)),
+            agg5[2] + lane_sum(jnp.where(head, e, zero)),
+            agg5[3] + lane_sum(jnp.where(tail, e, zero)),
+            agg5[4] + lane_sum(
+                jnp.where(tail, d * shifted(w), zero)
+                + jnp.where(fwd, (w + d) * shifted(d), zero)),
+        )
+        rho = _awi.acf_from_columns(*trial, ny=ny, Lp=Lp)
+        dev = _awi.measure_lags(rho, tab[:, 5:6], L, measure)   # [1, 1]
+        # deviation k goes to lane k % 128 of row k // 128
+        ro = jax.lax.div(k, jnp.int32(LANES))
+        out_ref[pl.ds(ro, 1), :] = jnp.where(
+            out_lane == jax.lax.rem(k, jnp.int32(LANES)), dev,
+            out_ref[pl.ds(ro, 1), :])
         if greedy:
             # Conditional commit: the candidate joins the running
             # reconstruction only when its trial deviation fits.
             take = (ok_ref[k] > 0) & (dev <= eps)
-            gate = take.astype(dtype)
-            z_ref[pl.dslice(s + L, Wy)] = (z_at + gate * d).reshape(Wy)
-            return jnp.where(take, trial5, agg5)
-        z_ref[pl.dslice(s + L, Wy)] = (z_at + d).reshape(Wy)
-        return trial5
+            d = jnp.where(take, d, jnp.zeros_like(d))
+            agg5 = tuple(jnp.where(take, t, a) for t, a in zip(trial, agg5))
+        else:
+            agg5 = trial
+        back = pltpu.roll(jnp.concatenate([d, pad_n], axis=1), c0, 1)
+        for i in range(NR):
+            z_ref[pl.ds(r0 + i, 1), :] = (
+                rows[i] + back[:, i * LANES:(i + 1) * LANES])
+        return agg5
 
-    jax.lax.fori_loop(0, K, step, agg_ref[...])
+    agg0 = tuple(tab[:, c:c + 1] for c in range(5))
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(K), step, agg0)
+
+
+def _prefix_layout(nyb: int, K: int, Wy: int, L: int):
+    """``(Lp, Wcp, NR, R)``: padded lags, context lanes, context rows and
+    reconstruction rows of :func:`prefix_devs_pallas`."""
+    LANES = _awi.LANES
+    Lp = _awi._pad8(L)
+    Wcp = -(-(Wy + 2 * Lp) // LANES) * LANES
+    NR = Wcp // LANES + 1
+    return Lp, Wcp, NR, (nyb - 1) // LANES + NR + 1
+
+
+def prefix_devs_footprint(nyb: int, K: int, Wy: int, L: int):
+    """``(vmem_bytes, smem_bytes)`` the fused prefix kernel holds at
+    float32: the running reconstruction (input block plus scratch) and the
+    candidates' delta rows in VMEM, their starts and gates in SMEM."""
+    _, Wcp, _, R = _prefix_layout(nyb, K, Wy, L)
+    return 4 * (2 * R * _awi.LANES + _awi._pad8(K) * Wcp), 8 * K
 
 
 @functools.partial(jax.jit,
@@ -389,70 +389,48 @@ def prefix_devs_pallas(y, dyws, ystarts, ok, agg_table, p0, ny, eps=None, *,
     only when its trial deviation fits within ``eps`` — the output is each
     candidate's *trial* deviation on top of the committed set, so the taken
     mask is recovered as ``ok & (out <= eps)``.
+
+    The whole call stays in fast memory (:func:`prefix_devs_footprint`);
+    the dispatch refuses it past the chip's budget, and
+    ``kernels/README.md`` records the largest buckets that compile for
+    v5e.
     """
-    from jax.experimental.pallas import tpu as pltpu
+    LANES = _awi.LANES
     K, Wy = dyws.shape
     nyb = y.shape[0]
     dtype = y.dtype
-    y_pad = jnp.pad(y.astype(dtype), (L, L + Wy))
+    Lp, Wcp, NR, R = _prefix_layout(nyb, K, Wy, L)
+    z0 = jnp.pad(y.astype(dtype), (Lp, R * LANES - nyb - Lp)).reshape(R, LANES)
     okf = ok.astype(dtype)
+    Kp = _awi._pad8(K)
+    d_lanes = jnp.pad(dyws.astype(dtype) * okf[:, None],
+                      ((0, Kp - K), (Lp, Wcp - Lp - Wy)))
     starts = jnp.clip(ystarts, 0, nyb - 1).astype(jnp.int32)
     ny_arr = jnp.asarray(ny, jnp.int32).reshape(1)
     eps_arr = jnp.asarray(
         jnp.inf if eps is None else eps, dtype).reshape(1)
 
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     kernel = functools.partial(
-        _prefix_scan_kernel, K=K, Wy=Wy, L=L, measure=measure, greedy=greedy)
-    return pl.pallas_call(
+        _prefix_scan_kernel, K=K, L=L, Lp=Lp, NR=NR, measure=measure,
+        greedy=greedy)
+    out = pl.pallas_call(
         kernel,
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec(dyws.shape, lambda i: (0, 0)),
-            pl.BlockSpec(starts.shape, lambda i: (0,)),
-            pl.BlockSpec(okf.shape, lambda i: (0,)),
-            pl.BlockSpec(y_pad.shape, lambda i: (0,)),
-            pl.BlockSpec(agg_table.shape, lambda i: (0, 0)),
-            pl.BlockSpec(p0.shape, lambda i: (0,)),
-            pl.BlockSpec(ny_arr.shape, lambda i: (0,)),
-            pl.BlockSpec(eps_arr.shape, lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((K,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((K,), dtype),
-        scratch_shapes=[pltpu.VMEM((nyb + 2 * L + Wy,), dtype)],
+        in_specs=[smem, smem, smem, smem, vmem, vmem, vmem],
+        out_specs=vmem,
+        out_shape=jax.ShapeDtypeStruct((_awi._pad8(-(-K // LANES)), LANES),
+                                       dtype),
+        scratch_shapes=[pltpu.VMEM((R, LANES), dtype)],
         interpret=interpret,
-    )(dyws.astype(dtype), starts, okf, y_pad, agg_table, p0, ny_arr,
-      eps_arr)
+    )(starts, okf, ny_arr, eps_arr, z0, d_lanes,
+      _awi.moment_table(agg_table.astype(dtype), p0, Lp))
+    return out.reshape(-1)[:K]
 
 
 # ---------------------------------------------------------------------------
 # dispatch
 # ---------------------------------------------------------------------------
-
-def prefix_devs(cfg, y, dyws, ystarts, ok, agg, p0, ny):
-    """Backend-dispatched deviation curve for one round's rank prefix.
-
-    The Pallas kernel runs only on a real TPU: its sequential per-lag
-    accumulation differs from the reference's vectorized reduction order,
-    so interpret-mode execution is reserved for the direct parity test
-    (tolerance-based) instead of the decision path — off-TPU, every
-    backend choice selects prefixes from the identical reference curve.
-    """
-    from repro.core import measures as _measures
-    from repro.kernels import ops as _ops
-    table = _ops.agg_to_table(agg)
-    L = cfg.lags
-    if _ops._kernel_eligible(cfg.backend, cfg.stat, cfg.measure) \
-            and not _ops.interpret_mode():
-        return prefix_devs_pallas(
-            y, dyws, ystarts, ok, table, p0, ny, L=L, measure=cfg.measure,
-            interpret=False)
-    rows = prefix_acf_rows_ref(y, dyws, ystarts, ok, table, ny, L=L)
-    if cfg.stat == "acf" and cfg.measure in _ref.KERNEL_MEASURES:
-        return _ref.measure_rows(rows, p0, cfg.measure)
-    mfn = _measures.get_measure(cfg.measure)
-    transform = _ops._transform_fn(cfg.stat)
-    return jax.vmap(lambda r: mfn(transform(r), p0))(rows)
-
 
 def greedy_feasible(cfg, y, dyws, ystarts, ok, agg, p0, ny, eps):
     """Backend-dispatched greedy feasible-subset selection for one round.
@@ -476,8 +454,9 @@ def greedy_feasible(cfg, y, dyws, ystarts, ok, agg, p0, ny, eps):
     table = _ops.agg_to_table(agg)
     L = cfg.lags
     dt = y.dtype
-    if _ops._kernel_eligible(cfg.backend, cfg.stat, cfg.measure) \
-            and not _ops.interpret_mode():
+    if _ops.use_fused_kernel(
+            cfg.backend, dt, cfg.stat, cfg.measure,
+            prefix_devs_footprint(y.shape[0], *dyws.shape, L)):
         devs = prefix_devs_pallas(
             y, dyws, ystarts, ok, table, p0, ny, eps, L=L,
             measure=cfg.measure, greedy=True, interpret=False)

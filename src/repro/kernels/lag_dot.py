@@ -2,13 +2,19 @@
 
 ExtractAggregates is O(nL), dominated by ``sxx_l = sum_t y_t * y_{t+l}``
 (paper §4.2); the four moment sums are O(n + L) prefix work and stay in XLA.
-The kernel streams the series through VMEM in blocks along the time axis and
-accumulates the [L] partial products across sequential grid steps (TPU grid
-iteration is sequential, so accumulation into the output block is safe).
 
-Each block loads ``[B + L]`` values (B-aligned slab + L halo from the next
-slab — zero past the series end, which also masks the invalid lag pairs) and
-runs an L-step loop of [1, B] multiply-reduce ops on the VPU.
+The kernel makes the lag sum a matmul.  The series is laid out as rows of
+128 lanes, ``A[r, c] = a[128 r + c]``, and likewise ``Bm`` for the
+lag-shifted operand.  A lag ``l = 128 q + s`` then pairs row ``r`` of ``A``
+with row ``r + q`` of ``Bm`` (lane ``c + s``) or row ``r + q + 1`` (lane
+``c + s - 128``), so every lag is a diagonal sum of one of the ``[128, 128]``
+products ``G_q = A^T Bm[q:]``.  Each grid step streams one ``[R, 128]``
+block of both operands (plus an 8-row halo of ``Bm`` from the next block),
+adds its ``Q = L // 128 + 2`` products to the resident ``[Q, 128, 128]``
+output on the MXU (grid steps run in order on the TPU, so accumulating
+into the output block is safe), and XLA takes the ``L`` diagonal sums at
+the end.  Every block offset is static or tile-aligned, so nothing in the
+body needs an unaligned dynamic slice.
 """
 from __future__ import annotations
 
@@ -16,26 +22,46 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
 
 
-def lag_dot_kernel(y_ref, yh_ref, out_ref, *, L: int, B: int, Lpad: int):
+def _zero():
+    # Index maps return int32: under jax_enable_x64 a bare Python 0 becomes
+    # an int64 constant, which the TPU lowering refuses.
+    return jnp.int32(0)
+
+
+def lag_dot_kernel(a_ref, b_ref, bh_ref, g_ref, bs_ref, *, R: int, Q: int):
     pid = pl.program_id(0)
 
     @pl.when(pid == 0)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        g_ref[...] = jnp.zeros_like(g_ref)
 
-    y_blk = y_ref[...].reshape(1, B)          # this slab
+    # Main block plus the next block's first rows, contiguous in scratch so
+    # each row shift q is one static (sublane-offset) load.
+    H = bh_ref.shape[0]
+    bs_ref[pl.ds(0, R), :] = b_ref[...]
+    bs_ref[pl.ds(R, H), :] = bh_ref[...]
+    a = a_ref[...]
+    for q in range(Q):
+        g_ref[q] += jax.lax.dot_general(
+            a, bs_ref[pl.ds(q, R), :], (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=g_ref.dtype)
 
-    def lag_body(lag, acc):
-        seg = yh_ref[0, pl.dslice(lag, B)].reshape(1, B)  # slab + halo ref
-        acc = acc.at[lag - 1].add(jnp.sum(y_blk * seg))
-        return acc
 
-    partial = jax.lax.fori_loop(
-        1, L + 1, lag_body, jnp.zeros((Lpad,), out_ref.dtype))
-    out_ref[...] += partial
+def _diagonal_index(L: int):
+    """Static ``(q, c, c')`` indices with ``out[l-1] = sum_c G[q, c, c']``."""
+    l = np.arange(1, L + 1)[:, None]
+    c = np.arange(LANES)[None, :]
+    col = c + l % LANES
+    q = l // LANES + col // LANES
+    return q, np.broadcast_to(c, q.shape), col % LANES
 
 
 @functools.partial(jax.jit, static_argnames=("L", "block", "interpret"))
@@ -44,44 +70,40 @@ def lag_dot_pallas(y, b=None, halo=None, *, L: int, block: int = 4096,
     """``out[l-1] = sum_{t < n} a_t * b_ext_{t+l}`` for l in 1..L, shape [L].
 
     With the defaults (``b=None, halo=None``) this is the Eq. 7 lagged
-    self-product ``sxx``.  The kernel body already separates the main operand
-    (``y``) from the lag-shifted one, so the same kernel computes *cross*
-    lagged products (``b``) and *halo'd* chunk-local products (``halo`` — an
-    L-point continuation of ``b`` past the chunk end, used by the
-    partitioned mode's overlap terms).
+    self-product ``sxx``.  ``b`` computes *cross* lagged products and
+    ``halo`` appends an L-point continuation of ``b`` past the chunk end
+    (the partitioned mode's overlap terms).  ``block`` is the number of
+    series points per grid step (rounded to whole 8-row tiles).
     """
     n = y.shape[0]
     dtype = y.dtype
-    B = block
-    pad = (-n) % B
-    npad = n + pad
-    Lpad = max(128, ((L + 127) // 128) * 128)   # lane-aligned accumulator
-    y_main = jnp.pad(y, (0, pad))
-    b_base = y if b is None else b
+    Q = L // LANES + 2
+    H = max(8, -(-(Q - 1) // 8) * 8)                 # halo rows per block
+    R = max(H, -(-(block // LANES) // H) * H)         # rows per block
+    nblk = -(-n // (R * LANES))
+    rows = nblk * R
+    b_base = y if b is None else b.astype(dtype)
     if halo is not None:
         b_base = jnp.concatenate([b_base, halo[:L].astype(dtype)])
-    y_halo = jnp.pad(b_base, (0, npad + Lpad - b_base.shape[0]))
+    a2 = jnp.pad(y, (0, rows * LANES - n)).reshape(rows, LANES)
+    b2 = jnp.pad(b_base, (0, (rows + R) * LANES - b_base.shape[0]))
+    b2 = b2.reshape(rows + R, LANES)
 
-    grid = (npad // B,)
-    kernel = functools.partial(lag_dot_kernel, L=L, B=B, Lpad=Lpad)
-    out = pl.pallas_call(
+    kernel = functools.partial(lag_dot_kernel, R=R, Q=Q)
+    g = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(nblk,),
         in_specs=[
-            pl.BlockSpec((B,), lambda i: (i,)),
-            # pre-materialized per-block halo slabs, one row per grid step
-            pl.BlockSpec((1, B + Lpad), lambda i: (i, 0)),
+            pl.BlockSpec((R, LANES), lambda i: (i, _zero())),
+            pl.BlockSpec((R, LANES), lambda i: (i, _zero())),
+            # the next block's first H rows (block index in units of H)
+            pl.BlockSpec((H, LANES), lambda i: ((i + 1) * (R // H), _zero())),
         ],
-        out_specs=pl.BlockSpec((Lpad,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((Lpad,), dtype),
+        out_specs=pl.BlockSpec((Q, LANES, LANES),
+                               lambda i: (_zero(), _zero(), _zero())),
+        out_shape=jax.ShapeDtypeStruct((Q, LANES, LANES), dtype),
+        scratch_shapes=[pltpu.VMEM((R + H, LANES), dtype)],
         interpret=interpret,
-    )(y_main, _halo_view(y_halo, npad, B, Lpad))
-    return out[:L]
-
-
-def _halo_view(y_halo, npad: int, B: int, Lpad: int):
-    """Materialize per-block halo slabs [nblocks, B + Lpad] so BlockSpec
-    indexing stays non-overlapping (Pallas blocks must tile the input)."""
-    nblocks = npad // B
-    idx = (jnp.arange(nblocks) * B)[:, None] + jnp.arange(B + Lpad)[None, :]
-    return y_halo[idx]
+    )(a2, b2, b2)
+    q, c, cc = _diagonal_index(L)
+    return jnp.sum(g[q, c, cc], axis=1)

@@ -14,33 +14,57 @@ Backend selection
 -----------------
 Three backends, chosen per-call (and plumbed from ``CameoConfig.backend``):
 
-* ``"pallas"``    — the hand-written Pallas kernels.  Native on TPU; in any
-  other process they execute in ``interpret=True`` mode, which runs the
-  kernel body through XLA on CPU — bit-faithful to the kernel semantics, so
-  allclose-vs-oracle checks validate the real kernel logic (but interpret
-  mode is *slow*; it is a correctness path, not a CPU fast path).
+* ``"pallas"``    — the hand-written Pallas kernels.  Compiled by Mosaic on
+  TPU; in any other process they execute in ``interpret=True`` mode, which
+  runs the kernel body through XLA on CPU — bit-faithful to the kernel
+  semantics, so allclose-vs-oracle checks validate the real kernel logic
+  (but interpret mode is *slow*; it is a correctness path, not a CPU fast
+  path).
 * ``"reference"`` — the pure-jnp forms from ``kernels/ref.py`` (chunked the
   same way the kernels tile VMEM, so peak memory matches).
 * ``"auto"``      — platform-detected default: ``"pallas"`` on TPU,
   ``"reference"`` everywhere else.
 
-Environment overrides (read at trace time):
+The dispatch rule (:func:`use_kernel`, the one place it is written)
+------------------------------------------------------------------
+A call runs its kernel when the backend resolves to ``"pallas"`` and the
+kernel can serve it:
+
+* ``stat="acf"`` with a measure in ``KERNEL_MEASURES`` (``mae | rmse |
+  cheb``, reduced in-kernel).  PACF and the other measures need the full
+  hypothetical-ACF rows; they have no kernel.
+* On a TPU (compiled, not interpreted) the operand dtype must be float32.
+  Mosaic has no float64: the default ``CameoConfig.dtype="float64"`` keeps
+  the Eq. 7 base aggregates, the dense Eq. 10/11 update and the exact
+  deviation in XLA (which emulates f64 on the chip), because the ε
+  guarantee rests on them.  The rounds loop ranks in float32 on purpose,
+  so its tier-impact kernel (``fused_round.window_rows_pallas``) is the
+  Pallas program of the default configuration.  Interpret mode runs either
+  dtype.
+* On a TPU, a kernel that keeps its whole problem in fast memory (the
+  fused prefix kernel, ``fused_round.prefix_devs_footprint``) must fit
+  ``VMEM_BUDGET`` / ``SMEM_BUDGET``.
+
+When the kernel cannot serve a call, ``"auto"`` takes the XLA reference form
+and an explicit ``backend="pallas"`` raises ``ValueError``: a requested
+kernel never turns into the reference in silence.  The fused-round twins
+(``fused_round.window_rows_pallas`` / ``prefix_devs_pallas``) add one
+condition (:func:`use_fused_kernel`): they are the decision path only when
+compiled, because their accumulation order differs from the reference
+reductions; off TPU every backend name selects through the reference form
+and the twins are exercised by the parity tests in interpret mode.
+
+Environment overrides (read at trace time; ``chip_smoke.py`` refuses both):
 
 * ``CAMEO_BACKEND=pallas|reference`` — overrides how ``"auto"`` resolves
   (explicit backend choices are never overridden).
 * ``CAMEO_FORCE_INTERPRET=1`` — forces ``interpret=True`` for the Pallas
   kernels even on TPU (kernel debugging).
-
-The Pallas kernels cover ``stat="acf"`` with the vector measures
-``mae | rmse | cheb`` reduced in-kernel.  Other measures and the PACF
-transform need the full hypothetical-ACF rows, so those configurations fall
-back to the reference math regardless of the requested backend (the
-``backend="pallas"`` vs ``"reference"`` parity guarantee is unaffected —
-both produce identical rankings either way).
 """
 from __future__ import annotations
 
 import os
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +79,12 @@ BACKENDS = ("auto", "pallas", "reference")
 
 # measures the kernels reduce in-register (others fall back to reference)
 KERNEL_MEASURES = _ref.KERNEL_MEASURES
+
+# Fast memory a whole-problem kernel (fused_round.prefix_devs_pallas) may
+# hold on a v5e core (128 MiB VMEM, 1 MiB SMEM), set below what compiled:
+# 58 MB of VMEM compiled and 87 MB did not (kernels/README.md).
+VMEM_BUDGET = 56 << 20
+SMEM_BUDGET = 896 << 10
 
 
 def resolve_backend(backend: str = "auto") -> str:
@@ -95,9 +125,47 @@ def _transform_fn(stat: str):
     raise ValueError(f"unknown stat {stat!r}")
 
 
-def _kernel_eligible(backend: str, stat: str, measure: str) -> bool:
-    return (resolve_backend(backend) == "pallas" and stat == "acf"
-            and measure in KERNEL_MEASURES)
+def kernel_refusal(dtype, stat: str = "acf", measure: str = "mae",
+                   footprint=(0, 0)) -> Optional[str]:
+    """Why the Pallas kernel cannot serve this call here, or ``None``.
+    ``footprint`` is the ``(vmem_bytes, smem_bytes)`` a kernel that keeps
+    its whole problem in fast memory needs."""
+    if stat != "acf" or measure not in KERNEL_MEASURES:
+        return (f"no kernel for stat={stat!r}, measure={measure!r} "
+                f"(kernels reduce acf with {KERNEL_MEASURES})")
+    if interpret_mode():
+        return None
+    if jnp.dtype(dtype) != jnp.float32:
+        return (f"Mosaic compiles float32 kernels only; the operand is "
+                f"{jnp.dtype(dtype).name}")
+    vmem, smem = footprint
+    if vmem > VMEM_BUDGET or smem > SMEM_BUDGET:
+        return (f"the kernel needs {vmem} B of VMEM and {smem} B of SMEM; "
+                f"the budget is {VMEM_BUDGET} and {SMEM_BUDGET}")
+    return None
+
+
+def use_kernel(backend: str, dtype, stat: str = "acf",
+               measure: str = "mae", footprint=(0, 0)) -> bool:
+    """The dispatch rule (module docstring): ``True`` runs the Pallas
+    kernel, ``False`` the XLA reference form.  An explicit
+    ``backend="pallas"`` that the kernel cannot serve raises."""
+    if resolve_backend(backend) != "pallas":
+        return False
+    why = kernel_refusal(dtype, stat, measure, footprint)
+    if why is None:
+        return True
+    if backend == "pallas":
+        raise ValueError(f"backend='pallas' cannot run here: {why}")
+    return False
+
+
+def use_fused_kernel(backend: str, dtype, stat: str = "acf",
+                     measure: str = "mae", footprint=(0, 0)) -> bool:
+    """:func:`use_kernel` for the fused-round twins, which are the decision
+    path only when compiled (never interpreted)."""
+    return (use_kernel(backend, dtype, stat, measure, footprint)
+            and not interpret_mode())
 
 
 # ---------------------------------------------------------------------------
@@ -113,7 +181,7 @@ def lag_dot(a, L: int, *, b=None, halo=None, block: int = 4096,
     continuation of ``b`` past the chunk end (the partitioned mode's
     cross-chunk overlap terms).
     """
-    if resolve_backend(backend) == "pallas":
+    if use_kernel(backend, a.dtype):
         return lag_dot_pallas(a, b, halo, L=L, block=block,
                               interpret=interpret_mode())
     b_ext = a if b is None else b
@@ -133,7 +201,7 @@ def acf_impact(y, dval, agg, p0, *, measure: str = "mae",
     """Algorithm-2 impacts for all points: D(ACF_after_delta_i, P0), [n]."""
     table = agg_to_table(agg)
     L = p0.shape[0]
-    if resolve_backend(backend) == "pallas":
+    if use_kernel(backend, y.dtype, "acf", measure):
         return acf_impact_pallas(
             y, dval, table, p0, L=L, measure=measure, block=block,
             interpret=interpret_mode())
@@ -145,7 +213,7 @@ def acf_impact(y, dval, agg, p0, *, measure: str = "mae",
 # ---------------------------------------------------------------------------
 
 def window_impact(y, dwins, starts, agg, p0, *, measure: str = "mae",
-                  block: int = 256, backend: str = "auto"):
+                  backend: str = "auto"):
     """Exact Eq. 9 impacts for P candidate windows against series ``y``.
 
     ``dwins [P, W]`` are zero-padded delta windows starting at ``starts [P]``
@@ -155,10 +223,10 @@ def window_impact(y, dwins, starts, agg, p0, *, measure: str = "mae",
     L = p0.shape[0]
     ny = y.shape[0]
     rows_ctx = _ref.candidate_contexts(y, starts, L=L, W=dwins.shape[1])
-    if resolve_backend(backend) == "pallas":
+    if use_kernel(backend, y.dtype, "acf", measure):
         return acf_window_impact_pallas(
             rows_ctx, dwins, starts, table, p0, ny=ny, L=L, measure=measure,
-            block=block, interpret=interpret_mode())
+            interpret=interpret_mode())
     return _ref.acf_window_impact_ref(
         rows_ctx, dwins, starts, table, p0, ny=ny, measure=measure)
 
@@ -171,25 +239,12 @@ def _measure_transform(cfg):
     return _measures.get_measure(cfg.measure), _transform_fn(cfg.stat)
 
 
-def _single_impacts_kernel(cfg, table, y, dval, p0, n: int):
-    """Kernel-path Eq. 8 impacts for all n x-candidates.
-
-    For ``kappa > 1`` the x→y index map ``i -> i // kappa`` is not unit
-    stride, so the contiguous-slice kernel runs once per residue class
-    ``i mod kappa`` — each class maps bijectively onto y positions.
-    """
-    kap = cfg.kappa
-    interp = interpret_mode()
-    if kap == 1:
-        return acf_impact_pallas(y, dval, table, p0, L=cfg.lags,
-                                 measure=cfg.measure, block=1024,
-                                 interpret=interp)
-    dmat = dval.reshape(n // kap, kap)
-    outs = [acf_impact_pallas(y, dmat[:, r], table, p0, L=cfg.lags,
-                              measure=cfg.measure, block=1024,
-                              interpret=interp)
-            for r in range(kap)]
-    return jnp.stack(outs, axis=-1).reshape(n)
+def _single_impacts_kernel(cfg, table, y, y_idx, dval, p0):
+    """Kernel-path Eq. 8 impacts for all n x-candidates (candidate ``i``
+    adds ``dval[i]`` at y position ``y_idx[i]``)."""
+    return acf_impact_pallas(y, dval, table, p0, idx=y_idx, L=cfg.lags,
+                             measure=cfg.measure, block=cfg.impact_chunk,
+                             interpret=interpret_mode())
 
 
 def _single_impacts_ref(cfg, agg, y, y_idx, dval, p0, n: int):
@@ -225,8 +280,9 @@ def _rank_single(cfg, agg, y, xr, alive, p0, n: int):
         y_idx = idx // cfg.kappa
         dval = dx / jnp.asarray(cfg.kappa, dt)
 
-    if _kernel_eligible(cfg.backend, cfg.stat, cfg.measure):
-        imp = _single_impacts_kernel(cfg, agg_to_table(agg), y, dval, p0, n)
+    if use_kernel(cfg.backend, y.dtype, cfg.stat, cfg.measure):
+        imp = _single_impacts_kernel(cfg, agg_to_table(agg), y, y_idx, dval,
+                                     p0)
     else:
         imp = _single_impacts_ref(cfg, agg, y, y_idx, dval, p0, n)
 
@@ -236,18 +292,18 @@ def _rank_single(cfg, agg, y, xr, alive, p0, n: int):
 
 
 def _window_chunk(cfg, agg, y_ctx, ystart, dyw, p0, off, ny: int,
-                  use_kernel: bool):
+                  kernel: bool):
     """Eq. 9 impacts for one chunk of candidates against a 1-D haloed
     context ``y_ctx`` (``y_ctx[k] = y_local[k - L]``, zeros out of range)."""
     L = cfg.lags
     mfn, transform = _measure_transform(cfg)
-    if use_kernel:
+    if kernel:
         Wy = dyw.shape[1]
         k = jnp.arange(Wy + 2 * L)
         rows_ctx = y_ctx[ystart[:, None] + k[None, :]]        # [c, Wy + 2L]
         return acf_window_impact_pallas(
             rows_ctx, dyw, off + ystart, agg_to_table(agg), p0, ny=ny, L=L,
-            measure=cfg.measure, block=256, interpret=interpret_mode())
+            measure=cfg.measure, interpret=interpret_mode())
     rows = _ref.acf_after_window_delta_ctx(
         agg, y_ctx, ystart, dyw, ny=ny, off=off)
     return jax.vmap(lambda r: mfn(transform(r), p0))(rows)
@@ -295,7 +351,7 @@ def _rank_window_ctx(cfg, agg, y_ctx, xr_loc, alive_loc, p0, off_y, ny: int,
     idx = jnp.arange(mx, dtype=jnp.int32)
     prev, nxt = alive_neighbors(alive_loc)
     inf = jnp.asarray(jnp.inf, dt)
-    use_kernel = _kernel_eligible(cfg.backend, cfg.stat, cfg.measure)
+    kernel = use_kernel(cfg.backend, y_ctx.dtype, cfg.stat, cfg.measure)
 
     chunk = min(cfg.impact_chunk, mx)
     pad = (-mx) % chunk
@@ -305,7 +361,7 @@ def _rank_window_ctx(cfg, agg, y_ctx, xr_loc, alive_loc, p0, off_y, ny: int,
         dwin, start, span = segment_deltas(xr_loc, prev, nxt, ci, W)
         dyw, ystart = x_window_to_y(cfg, dwin, start)
         imp = _window_chunk(cfg, agg, y_ctx, ystart, dyw, p0, off_y, ny,
-                            use_kernel)
+                            kernel)
         return imp.astype(dt), span
 
     nchunks = (mx + pad) // chunk
@@ -366,8 +422,8 @@ def window_impact_at(cfg, agg, y, xr, prev, nxt, cand, p0):
     dwin, start, span = segment_deltas(xr, prev, nxt, cand, W)
     dyw, ystart = x_window_to_y(cfg, dwin, start)
     y_ctx = jnp.pad(y, (L, L + W))
-    use_kernel = _kernel_eligible(cfg.backend, cfg.stat, cfg.measure)
-    imp = _window_chunk(cfg, agg, y_ctx, ystart, dyw, p0, 0, ny, use_kernel)
+    kernel = use_kernel(cfg.backend, y.dtype, cfg.stat, cfg.measure)
+    imp = _window_chunk(cfg, agg, y_ctx, ystart, dyw, p0, 0, ny, kernel)
     interior = (cand > 0) & (cand < n - 1)
     inf = jnp.asarray(jnp.inf, dt)
     return jnp.where((span <= W) & interior, imp.astype(dt), inf)

@@ -18,6 +18,16 @@ import jax
 import jax.numpy as jnp
 
 
+def cumsum(x: jax.Array, axis: int = 0) -> jax.Array:
+    """``jnp.cumsum``, except float64 on a TPU, which runs as an
+    ``associative_scan``: the TPU compiler takes about three minutes over
+    an emulated-f64 cumulative sum at any length (a reduce-window), and
+    about a second over the scan."""
+    if x.dtype == jnp.float64 and jax.default_backend() == "tpu":
+        return jax.lax.associative_scan(jnp.add, x, axis=axis)
+    return jnp.cumsum(x, axis=axis)
+
+
 def acf_from_moments(sx, sxl, sx2, sxl2, sxx, m):
     """Eq. 2: normalized per-lag ACF from the five moment sums.
 
@@ -99,7 +109,7 @@ def acf_after_single_delta(agg, y: jax.Array, idx: jax.Array,
 
     # Five flat [P, L] moment rows: a packed [P, 5, L] stack would be two
     # fewer dispatches but materializes 5 PL elements through a concat the
-    # legacy CPU runtime doesn't fuse — measurably slower at P = nb.
+    # CPU emitter doesn't fuse — measurably slower at P = nb.
     tab = as_table(agg)
     sx = tab[0][None, :] + d * head
     sxl = tab[1][None, :] + d * tail

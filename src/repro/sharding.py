@@ -35,15 +35,10 @@ def _ctx():
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable ``shard_map``: new jax exposes ``jax.shard_map``
-    (``check_vma``); older releases ship ``jax.experimental.shard_map``
-    (``check_rep``).  All repo call sites go through this wrapper."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    """``jax.shard_map`` with the repo's default ``check_vma=False``.  All
+    repo call sites go through this wrapper."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 @contextlib.contextmanager

@@ -51,19 +51,24 @@ def test_ranking_impact_backend_parity(rank, kappa, measure):
                                rtol=1e-9, atol=1e-9)
 
 
-def test_ranking_impact_pacf_falls_back():
-    """Configs the kernels can't serve (pacf / non-kernel measures) produce
-    identical results under both backend names (reference fallback)."""
+def test_ranking_impact_pacf_falls_back(monkeypatch):
+    """Configs the kernels can't serve (pacf / non-kernel measures): an
+    ``"auto"`` that resolves to pallas falls back to the reference math
+    (identical results), an explicit ``backend="pallas"`` raises."""
     n = 256
+    monkeypatch.setenv("CAMEO_BACKEND", "pallas")
     for kw in [dict(stat="pacf"), dict(measure="mape")]:
         cfg = CameoConfig(lags=8, backend="reference", **kw)
         x, xr, alive, y, agg, p0 = _ranking_setup(cfg, n, seed=3)
         a = ops.ranking_impact(cfg, agg, y, xr, alive, p0, n)
         b = ops.ranking_impact(
-            dataclasses.replace(cfg, backend="pallas"),
+            dataclasses.replace(cfg, backend="auto"),
             agg, y, xr, alive, p0, n)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-12, atol=1e-12)
+        with pytest.raises(ValueError, match="no kernel"):
+            ops.ranking_impact(dataclasses.replace(cfg, backend="pallas"),
+                               agg, y, xr, alive, p0, n)
 
 
 @pytest.mark.parametrize("rank", ["single", "window"])
@@ -119,6 +124,112 @@ def test_resolve_backend():
     assert ops.resolve_backend("auto") in ("pallas", "reference")
     with pytest.raises(ValueError):
         ops.resolve_backend("nope")
+
+
+def _steer_platform(monkeypatch, platform):
+    """Make the dispatch code see ``platform`` (no option of the program
+    does this; the compile itself never runs here)."""
+    monkeypatch.delenv("CAMEO_BACKEND", raising=False)
+    monkeypatch.delenv("CAMEO_FORCE_INTERPRET", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+
+
+def _rule(platform, dtype, stat, measure, backend):
+    """The documented dispatch rule, written out independently of ops:
+    ``"kernel"``, ``"reference"`` or ``"raises"``."""
+    if backend == "auto":
+        backend = "pallas" if platform == "tpu" else "reference"
+        explicit = False
+    else:
+        explicit = True
+    if backend == "reference":
+        return "reference"
+    servable = (stat == "acf" and measure in ("mae", "rmse", "cheb")
+                and (platform != "tpu" or dtype == "float32"))
+    if servable:
+        return "kernel"
+    return "raises" if explicit else "reference"
+
+
+@pytest.mark.parametrize("backend", ["auto", "pallas", "reference"])
+@pytest.mark.parametrize("measure", ["mae", "mape"])
+@pytest.mark.parametrize("stat", ["acf", "pacf"])
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_dispatch_rule(monkeypatch, platform, dtype, stat, measure, backend):
+    """``ops.use_kernel`` / ``use_fused_kernel`` for every (platform, dtype,
+    stat, measure, backend): float64 never reaches a compiled kernel, an
+    explicit pallas that cannot be honoured raises, and the fused-round
+    twins are the decision path only when compiled."""
+    _steer_platform(monkeypatch, platform)
+    want = _rule(platform, dtype, stat, measure, backend)
+    args = (backend, jnp.dtype(dtype), stat, measure)
+    if want == "raises":
+        with pytest.raises(ValueError, match="cannot run here"):
+            ops.use_kernel(*args)
+        with pytest.raises(ValueError, match="cannot run here"):
+            ops.use_fused_kernel(*args)
+        return
+    assert ops.use_kernel(*args) == (want == "kernel")
+    assert ops.use_fused_kernel(*args) == (want == "kernel"
+                                           and platform == "tpu")
+
+
+@pytest.mark.parametrize("nyb,K,Wy,L,fits", [
+    (18432, 1843, 64, 48, True),         # uk_elec bucket, scan select
+    (524288, 52428, 64, 48, True),       # compiled for v5e (README)
+    (786432, 78643, 64, 48, False),      # ran out of VMEM
+    (8739, 104868, 2, 24, True),         # solar bucket, kappa=120
+    (17477, 209724, 2, 24, False),       # ran out of SMEM
+])
+def test_dispatch_refuses_fused_kernel_past_fast_memory(monkeypatch, nyb, K,
+                                                        Wy, L, fits):
+    """The fused prefix kernel keeps its whole problem on chip: past the
+    fast-memory budget "auto" takes the reference form and an explicit
+    pallas raises (the compiles behind each case: kernels/README.md)."""
+    from repro.kernels.fused_round import prefix_devs_footprint
+    _steer_platform(monkeypatch, "tpu")
+    fp = prefix_devs_footprint(nyb, K, Wy, L)
+    assert ops.use_fused_kernel("auto", jnp.float32, footprint=fp) == fits
+    if fits:
+        assert ops.use_fused_kernel("pallas", jnp.float32, footprint=fp)
+    else:
+        with pytest.raises(ValueError, match="budget"):
+            ops.use_fused_kernel("pallas", jnp.float32, footprint=fp)
+
+
+@pytest.mark.parametrize("backend", ["auto", "pallas", "reference"])
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_lag_dot_runs_the_dispatched_path(monkeypatch, platform, dtype,
+                                          backend):
+    """The Eq. 7 lagged products go to the kernel exactly when the rule
+    says so, interpreted off the chip; an unservable explicit pallas
+    raises instead of running the reference."""
+    _steer_platform(monkeypatch, platform)
+    calls = []
+
+    def spy(a, b, halo, *, L, block, interpret):
+        calls.append(interpret)
+        return jnp.zeros((L,), a.dtype)
+
+    monkeypatch.setattr(ops, "lag_dot_pallas", spy)
+    y = _series(300).astype(dtype)
+    want = _rule(platform, dtype, "acf", "mae", backend)
+    if want == "raises":
+        with pytest.raises(ValueError, match="float32 kernels only"):
+            ops.lag_dot(y, 6, backend=backend)
+        assert calls == []
+        return
+    out = ops.lag_dot(y, 6, backend=backend)
+    if want == "kernel":
+        assert calls == [platform != "tpu"]
+    else:
+        assert calls == []
+        yn = np.asarray(y, np.float64)
+        np.testing.assert_allclose(
+            np.asarray(out), [yn[:-l] @ yn[l:] for l in range(1, 7)],
+            rtol=1e-5 if dtype == "float32" else 1e-12)
 
 
 def _prefix_setup(seed=9, nyb=160, ny=150, K=12, Wy=16, L=8):

@@ -75,21 +75,21 @@ def test_lag_dot_kernel_cross_and_halo(n, L):
                                rtol=1e-12, atol=1e-12)
 
 
-@pytest.mark.parametrize("n,L,W,block", [
-    (512, 12, 16, 128), (1000, 24, 64, 256), (513, 7, 32, 128),
+@pytest.mark.parametrize("n,L,W,P", [
+    (512, 12, 16, 128), (1000, 24, 64, 300), (513, 7, 32, 200),
 ])
 @pytest.mark.parametrize("measure", ["mae", "rmse", "cheb"])
-def test_acf_window_impact_kernel_sweep(n, L, W, block, measure):
-    """New Eq. 9 windowed-impact kernel vs its jnp oracle."""
+def test_acf_window_impact_kernel_sweep(n, L, W, P, measure):
+    """New Eq. 9 windowed-impact kernel vs its jnp oracle (``P`` candidates:
+    one whole 128-lane block, and partial last blocks)."""
     rng = np.random.default_rng(7)
     y, _, tab, p0 = _setup(n, L, np.float64, seed=7)
-    P = 200
     starts = jnp.asarray(rng.integers(0, n - 1, P), jnp.int32)
     spans = rng.integers(1, W + 1, P)
     dwins = rng.standard_normal((P, W)) * 0.1
     dwins = jnp.asarray(dwins * (np.arange(W)[None, :] < spans[:, None]))
     got = window_impact(y, dwins, starts, tab, p0, measure=measure,
-                        block=block, backend="pallas")
+                        backend="pallas")
     want = window_impact(y, dwins, starts, tab, p0, measure=measure,
                         backend="reference")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
