@@ -41,6 +41,9 @@ ARCHIVE = ("uk_elec", "min_temp", "aus_elec", "solar")
 # the default backend on the same points: one full-length solar compression
 # takes about 380 s on a v5e, and two do not fit the smoke's time limit.
 TWIN_CUT = {"solar": 16}
+# The four-chip phase compresses the same sixteenth of solar: its one-device
+# comparison alone would take minutes of four chips at full length.
+FOUR_CHIP_CUT = 16
 TENANTS = 8
 CHUNK = 731
 SERVER_WINDOW = 4096
@@ -93,6 +96,10 @@ def acf_deviation_np(x, xr, L: int, kappa: int) -> float:
 _COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                    "/jax/core/compile/jaxpr_to_mlir_module_duration",
                    "/jax/core/compile/backend_compile_duration")
+# persistent compile cache: a program read back, a program compiled and
+# written (JAX writes only programs that took at least a second)
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
+                 "/jax/compilation_cache/cache_misses": "cache_writes"}
 _seconds = {}
 _seconds_lock = threading.Lock()
 
@@ -102,10 +109,16 @@ def _on_duration(event: str, secs: float, **_):
         _seconds[event] = _seconds.get(event, 0.0) + secs
 
 
+def _on_event(event: str, **_):
+    if event in _CACHE_EVENTS:
+        with _seconds_lock:
+            _seconds[event] = _seconds.get(event, 0) + 1
+
+
 class Phase:
     """Wall seconds, compile seconds (trace + lower + backend compile;
-    a persistent-cache hit skips the backend compile) and the recompile
-    watermark of one phase."""
+    a persistent-cache hit skips the backend compile), persistent-cache
+    hits and writes, and the recompile watermark of one phase."""
 
     def __init__(self, name: str):
         self.name = name
@@ -122,11 +135,13 @@ class Phase:
         from repro import obs
         if exc[0] is None:
             with _seconds_lock:
-                d = {k: _seconds.get(k, 0.0) - self.c0.get(k, 0.0)
-                     for k in _COMPILE_EVENTS}
-            say(self.name, wall_s=time.perf_counter() - self.t0,
-                compile_s=sum(d.values()),
+                d = {k: _seconds.get(k, 0) - self.c0.get(k, 0)
+                     for k in (*_COMPILE_EVENTS, *_CACHE_EVENTS)}
+            say(self.name,
+                wall_s=time.perf_counter() - self.t0,
+                compile_s=sum(d[k] for k in _COMPILE_EVENTS),
                 backend_compile_s=d[_COMPILE_EVENTS[2]],
+                **{name: d[k] for k, name in _CACHE_EVENTS.items()},
                 recompile_watermark=obs.recompile_watermark(),
                 recompiles=obs.recompile_watermark() - self.w0)
 
@@ -167,18 +182,18 @@ def phase_device(chips: int) -> dict:
     return device
 
 
-def rounds_lowered(cfg, n: int, pad_to: int = 0):
-    """``_rounds_padded`` lowered exactly as ``compress_rounds`` calls it
-    for a length-``n`` series (so the call finds the compiled program)."""
-    import jax
-    import jax.numpy as jnp
-    from repro.core import cameo
-    nb = cameo._round_bucket(max(n, pad_to), cfg)
-    dt = cfg.jdtype()
-    scalar = jax.ShapeDtypeStruct((), jnp.int32)
-    return nb, cameo._rounds_padded.lower(
-        jax.ShapeDtypeStruct((nb,), dt), scalar, scalar,
-        jax.ShapeDtypeStruct((), dt), cfg=cfg)
+def rounds_cut_short(cfg, rounds: int, deviation: float) -> bool:
+    """The rounds loop stopped at its ``max_rounds`` cap with the deviation
+    well below its budget: the kept count is then the cap's, not the
+    method's."""
+    return rounds >= cfg.max_rounds and deviation < 0.5 * cfg.eps
+
+
+def write_rounds() -> float:
+    """Elimination rounds of every facade write so far (telemetry)."""
+    from repro import obs
+    h = obs.OBS.histogram("write.rounds")
+    return h.sum if h is not None else 0.0
 
 
 def phase_compile(server_window: int) -> None:
@@ -188,6 +203,7 @@ def phase_compile(server_window: int) -> None:
     Each series' default program must hold the Pallas ranking kernel
     (``tpu_custom_call``), compiled and not interpreted; its
     ``backend="reference"`` twin must hold none."""
+    from repro.core.cameo import lower_rounds
     from repro.kernels import ops
     backend, interp = ops.resolve_backend("auto"), ops.interpret_mode()
     say("dispatch", backend=backend, interpret=interp)
@@ -197,14 +213,14 @@ def phase_compile(server_window: int) -> None:
     for name in ARCHIVE:
         x = make_series(name)
         n, n_twin = len(x), len(twin_series(name, x))
-        jobs[f"{name}.auto"] = rounds_lowered(archive_cfg(name), n)
-        jobs[f"{name}.reference"] = rounds_lowered(
-            archive_cfg(name, "reference"), n_twin)
+        jobs[f"{name}.auto"] = lower_rounds(n, archive_cfg(name))
+        jobs[f"{name}.reference"] = lower_rounds(
+            n_twin, archive_cfg(name, "reference"))
         if n_twin < n:
-            jobs[f"{name}.twin.auto"] = rounds_lowered(archive_cfg(name),
-                                                       n_twin)
-    jobs["served.auto"] = rounds_lowered(archive_cfg("uk_elec"),
-                                         server_window, server_window)
+            jobs[f"{name}.twin.auto"] = lower_rounds(n_twin,
+                                                     archive_cfg(name))
+    jobs["served.auto"] = lower_rounds(server_window, archive_cfg("uk_elec"),
+                                       pad_to=server_window)
 
     def build(label):
         t0 = time.perf_counter()
@@ -225,7 +241,9 @@ def phase_compile(server_window: int) -> None:
 def phase_archive(path: Path) -> dict:
     """Write the four Table-1 series at full length through the dataset
     facade; re-measure each deviation on the decode; compress each again
-    on the reference backend (solar on a cut, ``TWIN_CUT``)."""
+    on the reference backend (solar on a cut, ``TWIN_CUT``).  Each line
+    carries the rounds every run took; a run that stopped at the
+    ``max_rounds`` cap far below its budget gets an ``archive.flag`` line."""
     import numpy as np
     from repro import api
     from repro.core.cameo import compress
@@ -236,19 +254,25 @@ def phase_archive(path: Path) -> dict:
             cfg = archive_cfg(name)
             x = make_series(name)
             xs[name] = x
+            r0 = write_rounds()
             with Phase(f"archive.{name}"):
                 ds.cfg = cfg
                 entry = ds.write(name, x)
                 xr = ds.series(name).window()
                 dev_np = acf_deviation_np(x, xr, cfg.lags, cfg.kappa)
+            runs = {"auto": (int(write_rounds() - r0), entry["deviation"])}
             xt = twin_series(name, x)
             with Phase(f"archive.{name}.reference"):
                 ref = compress(xt, dataclasses.replace(cfg, backend="reference"))
                 kept_ref = np.asarray(ref.kept)
                 dev_ref_np = acf_deviation_np(xt, np.asarray(ref.xr),
                                               cfg.lags, cfg.kappa)
+                runs["reference"] = (int(ref.iters), float(ref.deviation))
                 if len(xt) < len(x):
-                    kept = np.asarray(compress(xt, cfg).kept)
+                    twin = compress(xt, cfg)
+                    kept = np.asarray(twin.kept)
+                    runs["twin.auto"] = (int(twin.iters),
+                                         float(twin.deviation))
                 else:
                     kept = np.zeros(len(x), bool)
                     kept[ds.series(name).kept()[0]] = True
@@ -262,7 +286,15 @@ def phase_archive(path: Path) -> dict:
                 cr_reference=len(xt) / int(kept_ref.sum()),
                 deviation_reference=float(ref.deviation),
                 deviation_reference_np=dev_ref_np,
-                same_kept=bool(np.array_equal(kept, kept_ref)))
+                same_kept=bool(np.array_equal(kept, kept_ref)),
+                max_rounds=cfg.max_rounds,
+                rounds={run: r for run, (r, _) in runs.items()})
+            for run, (r, d) in runs.items():
+                if rounds_cut_short(cfg, r, d):
+                    say("archive.flag", series=name, run=run, rounds=r,
+                        deviation=d, eps=EPS,
+                        note="stopped at max_rounds below eps/2: the kept "
+                             "count is the cap's, not the method's")
             check(dev_np <= EPS, f"{name}: decoded ACF deviation {dev_np} > "
                                  f"eps {EPS}")
             check(abs(dev_np - entry["deviation"]) <= 1e-9,
@@ -386,10 +418,9 @@ def phase_four_chips() -> None:
     against the one-device partitioned form, and the sharded fleet batch
     against its one-device vmap.
 
-    Two threads overlap the compiles: one runs the two mesh programs in
-    turn, the other the two one-device programs on device 0.  Only one
-    program with collectives is in flight at a time, so no two of them can
-    wait on each other across devices."""
+    The four runs go one after another, each reported when it returns, so
+    a run that never returns is named by the last line before it.  Each
+    one-device form runs on a chip of its own (devices 0 and 1)."""
     import jax
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -411,27 +442,28 @@ def phase_four_chips() -> None:
 
     cfg_p, cfg_b = archive_cfg("solar"), archive_cfg("uk_elec")
     x = make_series("solar")
-    n = (len(x) // (4 * cfg_p.kappa)) * 4 * cfg_p.kappa
+    n = (len(x) // FOUR_CHIP_CUT // (4 * cfg_p.kappa)) * 4 * cfg_p.kappa
     x = x[:n]
     xs = np.stack([make_series("uk_elec", seed=i) for i in range(8)])
 
-    def on_mesh():
-        return (done(compress_partitioned_shardmap(placed(x), cfg_p, mesh,
-                                                   axis="data")),
-                done(compress_batch(placed(xs), cfg_b, mesh=mesh,
-                                    axis="data")))
-
-    def on_one_device():
-        return (done(compress_partitioned(jax.device_put(x, devs[0]), cfg_p,
-                                          T=4)),
-                done(compress_batch(jax.device_put(xs, devs[0]), cfg_b)))
-
+    runs = {
+        "part_mesh": lambda: compress_partitioned_shardmap(
+            placed(x), cfg_p, mesh, axis="data"),
+        "part_one": lambda: compress_partitioned(
+            jax.device_put(x, devs[0]), cfg_p, T=4),
+        "batch_mesh": lambda: compress_batch(placed(xs), cfg_b, mesh=mesh,
+                                             axis="data"),
+        "batch_one": lambda: compress_batch(jax.device_put(xs, devs[1]),
+                                            cfg_b),
+    }
+    got = {}
     with Phase("four_chips"):
-        with concurrent.futures.ThreadPoolExecutor(2) as pool:
-            mesh_run = pool.submit(on_mesh)
-            one_run = pool.submit(on_one_device)
-            (part_s, batch_s), (part_1, batch_1) = (mesh_run.result(),
-                                                    one_run.result())
+        for label, run in runs.items():
+            say("four_chips.start", run=label)
+            with Phase(f"four_chips.{label}"):
+                got[label] = done(run())
+    part_s, part_1 = got["part_mesh"], got["part_one"]
+    batch_s, batch_1 = got["batch_mesh"], got["batch_one"]
 
     dev_s, dev_1 = float(part_s.deviation), float(part_1.deviation)
     dev_np = acf_deviation_np(x, part_s.xr, cfg_p.lags, cfg_p.kappa)
@@ -439,7 +471,9 @@ def phase_four_chips() -> None:
         n_kept=int(part_s.kept.sum()), cr=n / int(part_s.kept.sum()),
         n_kept_one_device=int(part_1.kept.sum()),
         same_kept=bool(np.array_equal(part_s.kept, part_1.kept)),
-        deviation=dev_s, deviation_one_device=dev_1, deviation_np=dev_np)
+        deviation=dev_s, deviation_one_device=dev_1, deviation_np=dev_np,
+        rounds=int(part_s.iters), rounds_one_device=int(part_1.iters),
+        max_rounds=cfg_p.max_rounds)
     check(np.array_equal(part_s.kept, part_1.kept),
           "partitioned kept sets differ")
     check(abs(dev_s - dev_1) < 1e-9, f"deviations {dev_s} vs {dev_1}")
@@ -450,7 +484,7 @@ def phase_four_chips() -> None:
         n_kept=batch_s.kept.sum(axis=1).tolist(),
         same_kept=bool(np.array_equal(batch_s.kept, batch_1.kept)),
         max_deviation=float(batch_s.deviation.max()),
-        max_deviation_gap=gap)
+        max_deviation_gap=gap, rounds=batch_s.iters.tolist())
     check(np.array_equal(batch_s.kept, batch_1.kept),
           "batched kept sets differ")
     check(gap < 1e-9, f"batched deviations differ by {gap}")
@@ -479,9 +513,12 @@ def main(argv=None) -> int:
     import jax
     from jax import monitoring
     jax.config.update("jax_enable_x64", True)
+    from repro import obs
     from repro.compile_cache import enable_compile_cache
     cache_dir = enable_compile_cache()
     monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
+    obs.enable()    # the archive phase reads each write's rounds from it
 
     device = phase_device(args.chips)
     say("setup", native_scan=native_scan, compile_cache=cache_dir,

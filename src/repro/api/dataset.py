@@ -535,6 +535,7 @@ class Dataset:
             sid, res, cfg, x=x if self.store_residuals else None)
         if OBS.enabled:
             OBS.observe("write.seconds", _perf_counter() - t0)
+            OBS.observe("write.rounds", int(res.iters))
             OBS.inc("write.series")
             devs = np.atleast_1d(entry.get("deviations", entry["deviation"]))
             budget = (eps_c if eps_c is not None

@@ -46,6 +46,7 @@ from repro.core import measures as _measures
 from repro.core.acf import (
     acf_from_aggregates,
     aggregate_series,
+    centered,
     extract_aggregates,
     extract_aggregates_masked,
 )
@@ -660,15 +661,7 @@ def _rounds_init(xp: jax.Array, n_valid: jax.Array, cfg: CameoConfig):
     validm = idx < n_valid
     xp = jnp.where(validm, xp.astype(dt), jnp.asarray(0.0, dt))
     ny_valid = n_valid // cfg.kappa
-    y0 = aggregate_series(xp, cfg.kappa)
-    # The target series is carried about its mean.  Eq. 2 does not change
-    # under a constant shift, while raw moments of a series far from zero
-    # lose the ACF to cancellation in ``m * sxx - sx * sxl``: with float64
-    # emulated in float32 pairs (TPU) the carried deviation drifted 4e-9
-    # from a fresh float64 measurement on electricity-demand data.
-    yvalid = jnp.arange(y0.shape[0]) < ny_valid
-    mu = jnp.sum(jnp.where(yvalid, y0, 0.0)) / ny_valid.astype(dt)
-    y0 = jnp.where(yvalid, y0 - mu, jnp.asarray(0.0, dt))
+    y0 = centered(aggregate_series(xp, cfg.kappa), ny_valid)
     agg0 = extract_aggregates_masked(y0, cfg.lags, ny_valid,
                                      backend=cfg.backend)
     tbl0 = _ops.agg_to_table(agg0)
@@ -745,20 +738,38 @@ def compress_rounds(x: jax.Array, cfg: CameoConfig, *,
     streaming callers pass their full window length so a partial tail
     window reuses the full-window program (no per-length recompiles).
     """
-    dt = cfg.jdtype()
-    x = jnp.asarray(x, dt)
+    x = jnp.asarray(x, cfg.jdtype())
     n = x.shape[0]
     if cfg.kappa > 1 and n % cfg.kappa:
         raise ValueError(f"length {n} not divisible by kappa={cfg.kappa}")
-    nb = _round_bucket(max(n, int(pad_to or 0)), cfg)
+    nb, avals = _rounds_signature(n, cfg, pad_to)
     xp = jnp.pad(x, (0, nb - n)) if nb > n else x
     min_alive, eps = _halting_params(n, cfg)
     res = _rounds_padded(
-        xp, jnp.asarray(n, jnp.int32), jnp.asarray(min_alive, jnp.int32),
-        jnp.asarray(eps, dt), cfg)
+        *(jnp.asarray(a, s.dtype)
+          for a, s in zip((xp, n, min_alive, eps), avals)), cfg)
     if nb == n:
         return res
     return res._replace(kept=res.kept[:n], xr=res.xr[:n])
+
+
+def _rounds_signature(n: int, cfg: CameoConfig, pad_to: Optional[int]):
+    """``(bucket, operand shapes)`` of the ``_rounds_padded`` call that
+    ``compress_rounds`` makes for a length-``n`` series."""
+    nb = _round_bucket(max(n, int(pad_to or 0)), cfg)
+    dt = cfg.jdtype()
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    return nb, (jax.ShapeDtypeStruct((nb,), dt), i32, i32,
+                jax.ShapeDtypeStruct((), dt))
+
+
+def lower_rounds(n: int, cfg: CameoConfig, *, pad_to: Optional[int] = None):
+    """``(bucket, lowered program)``: the rounds program ``compress_rounds``
+    runs for a length-``n`` series, lowered without running it.  Compiling
+    it ahead (``.compile()``) fills the persistent compile cache that the
+    later call then reads, and its text shows which kernels it holds."""
+    nb, avals = _rounds_signature(n, cfg, pad_to)
+    return nb, _rounds_padded.lower(*avals, cfg=cfg)
 
 
 # the rounds program is the streaming hot path: its compiled-variant count
@@ -779,7 +790,7 @@ def compress_sequential(x: jax.Array, cfg: CameoConfig) -> CompressResult:
     W = cfg.window
     h = cfg.hops
     kap = cfg.kappa
-    y0 = aggregate_series(x, kap)
+    y0 = centered(aggregate_series(x, kap))
     ny = y0.shape[0]
     agg0 = extract_aggregates(y0, L, backend=cfg.backend)
     transform = _stat_transform(cfg)
@@ -1220,8 +1231,10 @@ def _column_deviation(x_col: np.ndarray, xr_col: np.ndarray,
     """Exact measured D(S(recon), S(orig)) of one column (Eq. 7 path)."""
     transform = _stat_transform(cfg)
     mfn = _measure_fn(cfg)
-    y0 = aggregate_series(jnp.asarray(x_col, cfg.jdtype()), cfg.kappa)
-    y1 = aggregate_series(jnp.asarray(xr_col, cfg.jdtype()), cfg.kappa)
+    y0 = centered(aggregate_series(jnp.asarray(x_col, cfg.jdtype()),
+                                   cfg.kappa))
+    y1 = centered(aggregate_series(jnp.asarray(xr_col, cfg.jdtype()),
+                                   cfg.kappa))
     ny = int(y0.shape[0])
     s0 = transform(acf_from_aggregates(
         extract_aggregates(y0, cfg.lags, backend=cfg.backend), ny))

@@ -36,7 +36,8 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import sharding as shd
-from repro.core.acf import Aggregates, acf_from_aggregates, aggregate_series, acf
+from repro.core.acf import (Aggregates, acf, acf_from_aggregates,
+                            aggregate_series, centered)
 from repro.core.cameo import (
     CameoConfig,
     CompressResult,
@@ -184,9 +185,7 @@ def compress_partitioned(x: jax.Array, cfg: CameoConfig, T: int) -> CompressResu
         )(yparts, right_halo(yparts, L), offs_y)
         return jax.tree.map(lambda a: a.sum(0), contribs)
 
-    yp0 = jax.vmap(lambda c: aggregate_series(c, kap))(xp)
-    # moments about the mean, as in cameo._rounds_init
-    yp0 = yp0 - jnp.sum(jnp.sum(yp0, axis=1)) / ny
+    yp0 = centered(jax.vmap(lambda c: aggregate_series(c, kap))(xp))
     agg0 = global_agg_from(yp0)
     p0 = transform(acf_from_aggregates(agg0, ny))
 
@@ -284,9 +283,7 @@ def compress_partitioned_shardmap(x, cfg: CameoConfig, mesh, axis: str = "data")
     def body_shard(x_c):
         x_c = x_c.astype(dt)
         off_y = jax.lax.axis_index(axis).astype(jnp.int32) * my
-        y0 = aggregate_series(x_c, kap)
-        # moments about the mean, as in compress_partitioned
-        y0 = y0 - jax.lax.psum(jnp.sum(y0), axis) / ny
+        y0 = centered(aggregate_series(x_c, kap), ny, axis_name=axis)
         agg0 = jax.tree.map(
             lambda a: jax.lax.psum(a, axis),
             chunk_agg_contrib(y0, right_halo(y0, L), off_y, ny, L,
@@ -381,8 +378,8 @@ def compress_partitioned_local(x, cfg: CameoConfig, T: int, p: float = 1.0):
     xr = res.xr.reshape(n)
     transform = _stat_transform(cfg)
     mfn = _measure_fn(cfg)
-    y_orig = aggregate_series(x, cfg.kappa)
-    y_new = aggregate_series(xr, cfg.kappa)
+    y_orig = centered(aggregate_series(x, cfg.kappa))
+    y_new = centered(aggregate_series(xr, cfg.kappa))
     s0 = transform(acf(y_orig, cfg.lags))
     s1 = transform(acf(y_new, cfg.lags))
     dev = mfn(s1, s0)

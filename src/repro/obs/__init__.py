@@ -30,6 +30,7 @@ Metric name inventory (the production names; benchmarks reuse them)
 ``stream.queue_depth`` (gauge) / ``stream.queue_drains`` / ``stream.drain_windows``
 ``mvar.repair_halvings``           per-column eps repair loop halvings
 ``write.seconds`` / ``write.eps_headroom``  one-shot facade writes
+``write.rounds``                   elimination rounds per facade write (hist)
 ``store.cache.hits|misses|evictions``  decoded-block LRU traffic
 ``store.read.mmap_bytes|pread_bytes``  body bytes by read path
 ``store.read.coalesced_runs|blocks_fetched``  pread coalescing

@@ -8,8 +8,8 @@ import pytest
 
 from repro.core import measures
 from repro.core.acf import acf, aggregate_series, pacf_from_acf
-from repro.core.cameo import (CameoConfig, compress, compression_ratio,
-                              decompress, kept_points)
+from repro.core.cameo import (CameoConfig, compress, compress_multivariate,
+                              compression_ratio, decompress, kept_points)
 
 
 def _series(n, seed=0):
@@ -40,6 +40,47 @@ def test_eps_guarantee_and_exact_reporting(mode):
     # the reported deviation is exact w.r.t. the true reconstruction
     assert abs(_true_deviation(x, res, cfg) - float(res.deviation)) < 1e-8
     assert compression_ratio(res) > 1.5
+
+
+def _acf_centred_np(y, L):
+    """Eq. 2 per lag in plain float64 numpy: the Pearson correlation of
+    ``y[:-l]`` with ``y[l:]``, each side about its own mean."""
+    y = np.asarray(y, np.float64)
+    out = np.zeros(L)
+    for l in range(1, L + 1):
+        a, b = y[:-l] - y[:-l].mean(), y[l:] - y[l:].mean()
+        out[l - 1] = a @ b / np.sqrt((a @ a) * (b @ b))
+    return out
+
+
+@pytest.mark.parametrize("mode", ["rounds", "sequential", "multivariate"])
+def test_offset_series_matches_centred_reference(mode):
+    """A series riding far from zero: the target ACF and every reported
+    deviation agree with a centred numpy reference.  Raw Eq. 7 moments
+    would lose both to cancellation in ``m * sxx - sx * sxl``."""
+    offset = 1e6
+    cfg = CameoConfig(eps=0.02, lags=12, dtype="float64",
+                      mode="rounds" if mode == "multivariate" else mode)
+    L = cfg.lags
+    if mode == "multivariate":
+        X = np.stack([np.asarray(_series(512)) + offset,
+                      np.asarray(_series(512, seed=1)) - 3 * offset], axis=1)
+        res = compress_multivariate(X, cfg)
+        for c in range(X.shape[1]):
+            dev = np.mean(np.abs(_acf_centred_np(res.xr[:, c], L)
+                                 - _acf_centred_np(X[:, c], L)))
+            assert abs(float(res.deviations[c]) - dev) < 1e-9
+            assert dev <= cfg.eps + 1e-12
+        return
+    x = np.asarray(_series(512)) + offset
+    res = compress(jnp.asarray(x), cfg)
+    want = _acf_centred_np(x, L)
+    np.testing.assert_allclose(np.asarray(res.stat_orig), want,
+                               rtol=0, atol=1e-9)
+    dev = np.mean(np.abs(_acf_centred_np(res.xr, L) - want))
+    assert abs(float(res.deviation) - dev) < 1e-9
+    assert dev <= cfg.eps + 1e-12
+    assert int(res.n_kept) < x.shape[0]
 
 
 def test_kept_points_bit_exact():
