@@ -54,7 +54,7 @@ from repro.core.streaming import (
     StreamingCompressor,
     compressor_from_state,
 )
-from repro.obs import OBS
+from repro.obs import NULL_SPAN, OBS, span
 from repro.store import query as _query
 from repro.store import wal as _wal
 from repro.store.store import DEFAULT_CACHE_BYTES, CameoStore
@@ -403,20 +403,15 @@ class StreamWriter:
         crash at any later point replays them on ``resume`` — so a return
         from ``push`` means the data cannot be silently lost, even though
         its compressed form may not exist yet."""
-        if not OBS.enabled:
-            if self._wal is not None:
-                self._journal(np.asarray(chunk))
-            wins = self._comp.push(chunk)
-            self._sess.append_windows(wins)
-            return len(wins)
-        t0 = _perf_counter()
         if self._wal is not None:
-            self._journal(np.asarray(chunk))
-            OBS.observe("ingest.ack_seconds", _perf_counter() - t0)
+            with span("wal.append") as sp:
+                self._journal(np.asarray(chunk))
+            if sp is not NULL_SPAN:
+                OBS.observe("ingest.ack_seconds", sp.seconds)
         wins = self._comp.push(chunk)
-        self._sess.append_windows(wins)
-        OBS.observe("ingest.push_seconds", _perf_counter() - t0)
-        OBS.inc("ingest.points", int(np.shape(np.asarray(chunk))[0]))
+        if wins:
+            with span("store.append"):
+                self._sess.append_windows(wins)
         return len(wins)
 
     def flush(self) -> None:

@@ -298,6 +298,7 @@ def _round_fns(cfg: CameoConfig, nb: int, n_valid: jax.Array,
     # bandwidth of the per-round O(nL) ranking kernels.
     rdt = jnp.float32
 
+    @jax.named_scope("rank")
     def tier_impacts(mask, xr, yr, tbl_r, prev, nxt, Wt, cap):
         """Eq. 9 ranking impacts for the first ``cap`` mask positions; +inf
         elsewhere.  Returns (impact [nb], ranked-mask [nb])."""
@@ -335,6 +336,7 @@ def _round_fns(cfg: CameoConfig, nb: int, n_valid: jax.Array,
             imp_full = some(None)
         return imp_full, ranked
 
+    @jax.named_scope("rank")
     def single_impacts(xr, yr, tbl_r, prev, nxt):
         """Eq. 8 single-delta impacts for every point (exact at span 1)."""
         xhat = interpolate_at(xr, prev, nxt, idx)
@@ -381,6 +383,7 @@ def _round_fns(cfg: CameoConfig, nb: int, n_valid: jax.Array,
             ),
         )
 
+        @jax.named_scope("update")
         def dense_apply(sel_idx_a, take):
             """Authoritative dense evaluation of removing the rank positions
             marked in ``take``."""
@@ -403,41 +406,52 @@ def _round_fns(cfg: CameoConfig, nb: int, n_valid: jax.Array,
                           p0)
             return dev_new, sel, alive_new, xr_new, dy, tbl_new, prev_n, nxt_n
 
+        @jax.named_scope("rank")
+        def rank_all(cb: int, cc: int):
+            """Ranking impacts of every candidate (Eq. 8 at span 1, Eq. 9
+            tiers above it, with tier capacities ``cb``/``cc``): returns
+            (impact, exact-ranked mask, overflowed mask)."""
+            if cfg.rank == "single":
+                impact = jnp.where(cand, imp_sd, inf)
+                exact_ranked = cand & (span == 1)
+                overflowed = jnp.zeros((nb,), bool)
+            else:
+                a_mask = cand & (span == 1)
+                b_mask = cand & (span >= 2) & (span <= WB)
+                imp_b, ranked_b = tier_impacts(
+                    b_mask, xr, y_r, tbl_r, prev, nxt, WB, cb)
+                impact = jnp.where(a_mask, imp_sd, inf)
+                impact = jnp.where(b_mask, imp_b, impact)
+                exact_ranked = a_mask | (b_mask & ranked_b)
+                overflowed = b_mask & (~ranked_b)
+                if WB < W and tier_c:
+                    c_mask = cand & (span > WB) & (span <= W)
+                    imp_c, ranked_c = tier_impacts(
+                        c_mask, xr, y_r, tbl_r, prev, nxt, W, cc)
+                    impact = jnp.where(c_mask, imp_c, impact)
+                    exact_ranked = exact_ranked | (c_mask & ranked_c)
+                    overflowed = overflowed | (c_mask & (~ranked_c))
+                # Overgrown segments (span > W): unrankable exactly.
+                # Under a finite eps they stay unremovable; in the
+                # Def. 3 regime (eps = inf) the deviation never gates
+                # acceptance, so they are admitted with a large rank
+                # penalty (ordered by the Eq. 8 estimate) and validated
+                # by the dense authoritative update.
+                over_mask = cand & (span > W)
+                over_val = jnp.where(jnp.isfinite(eps), inf,
+                                     jnp.asarray(1e30, dt) + imp_sd)
+                impact = jnp.where(over_mask, over_val, impact)
+            return impact, exact_ranked, overflowed
+
         def round_at(k_rows: int, cb: int, cc: int):
             """Ranking + selection at one static problem size.  Outputs are
-            padded to ``k_max`` so both size branches unify shapes."""
+            padded to ``k_max`` so both size branches unify shapes.  The
+            named scopes ``rank``/``select``/``update`` mark the round's
+            phases in the compiled program's ``op_name`` metadata (an
+            operation belongs to the innermost of them)."""
+            @jax.named_scope("select")
             def go(_):
-                if cfg.rank == "single":
-                    impact = jnp.where(cand, imp_sd, inf)
-                    exact_ranked = cand & (span == 1)
-                    overflowed = jnp.zeros((nb,), bool)
-                else:
-                    a_mask = cand & (span == 1)
-                    b_mask = cand & (span >= 2) & (span <= WB)
-                    imp_b, ranked_b = tier_impacts(
-                        b_mask, xr, y_r, tbl_r, prev, nxt, WB, cb)
-                    impact = jnp.where(a_mask, imp_sd, inf)
-                    impact = jnp.where(b_mask, imp_b, impact)
-                    exact_ranked = a_mask | (b_mask & ranked_b)
-                    overflowed = b_mask & (~ranked_b)
-                    if WB < W and tier_c:
-                        c_mask = cand & (span > WB) & (span <= W)
-                        imp_c, ranked_c = tier_impacts(
-                            c_mask, xr, y_r, tbl_r, prev, nxt, W, cc)
-                        impact = jnp.where(c_mask, imp_c, impact)
-                        exact_ranked = exact_ranked | (c_mask & ranked_c)
-                        overflowed = overflowed | (c_mask & (~ranked_c))
-                    # Overgrown segments (span > W): unrankable exactly.
-                    # Under a finite eps they stay unremovable; in the
-                    # Def. 3 regime (eps = inf) the deviation never gates
-                    # acceptance, so they are admitted with a large rank
-                    # penalty (ordered by the Eq. 8 estimate) and validated
-                    # by the dense authoritative update.
-                    over_mask = cand & (span > W)
-                    over_val = jnp.where(jnp.isfinite(eps), inf,
-                                         jnp.asarray(1e30, dt) + imp_sd)
-                    impact = jnp.where(over_mask, over_val, impact)
-
+                impact, exact_ranked, overflowed = rank_all(cb, cc)
                 # Rank keys in float32: CPU/TPU top_k has a fast path there,
                 # and ranking order only steers the heuristic selection —
                 # every removal is still validated by the exact dense update

@@ -78,7 +78,7 @@ from repro.core.cameo import (
     compress_rounds,
 )
 from repro.kernels import ops as _ops
-from repro.obs import OBS
+from repro.obs import NULL_SPAN, OBS, span
 
 
 def compile_cache_size() -> int:
@@ -380,12 +380,13 @@ class StreamingCompressor:
         if len(q) == 1 or self.cfg.mode != "rounds":
             return [self._close(w, final=False, start=s) for s, w in q]
         xs = np.stack([w for _, w in q])
-        res = compress_batch(xs, self.cfg)   # one dispatch for all K windows
-        devs = np.asarray(res.deviation) if OBS.enabled else None
+        with span("stream.window.rounds"):
+            res = compress_batch(xs, self.cfg)   # one dispatch, K windows
+            kept, xr = np.asarray(res.kept), np.asarray(res.xr)
+            iters = np.asarray(res.iters)
+            devs = np.asarray(res.deviation) if OBS.enabled else None
         return [self._close(w, final=False, start=s,
-                            precomputed=(np.asarray(res.kept[i]),
-                                         np.asarray(res.xr[i]),
-                                         int(res.iters[i]),
+                            precomputed=(kept[i], xr[i], int(iters[i]),
                                          None if devs is None
                                          else float(devs[i])))
                 for i, (s, w) in enumerate(q)]
@@ -397,37 +398,46 @@ class StreamingCompressor:
             start = self._next_start
         m = w_x.shape[0]
         ndiv = (m // cfg.kappa) * cfg.kappa
+        # too short for the aggregate math: kept verbatim, uncompressed
+        verbatim = precomputed is None and ndiv // cfg.kappa < cfg.lags + 2
+        with NULL_SPAN if verbatim else span("stream.window"):
+            return self._close_window(w_x, start, m, ndiv, verbatim,
+                                      precomputed)
+
+    def _close_window(self, w_x, start, m, ndiv, verbatim, precomputed):
+        cfg = self.cfg
         dev = None
-        verbatim = False
         if precomputed is not None:     # full window closed by a batch drain
             kept, xr, iters, dev = precomputed
-        elif ndiv // cfg.kappa >= cfg.lags + 2:
-            if cfg.mode == "rounds":
-                # pad to the full-window bucket: a partial tail reuses the
-                # full-window program instead of compiling its own shape
-                res = compress_rounds(jnp.asarray(w_x[:ndiv], cfg.jdtype()),
-                                      cfg, pad_to=self.window_len)
-            else:
-                res = compress(jnp.asarray(w_x[:ndiv]), cfg)
-            kept = np.asarray(res.kept)
-            xr = np.asarray(res.xr)
-            iters = int(res.iters)
-            if OBS.enabled:
-                dev = float(res.deviation)
+        elif not verbatim:
+            with span("stream.window.rounds"):
+                if cfg.mode == "rounds":
+                    # pad to the full-window bucket: a partial tail reuses
+                    # the full-window program instead of compiling its own
+                    res = compress_rounds(
+                        jnp.asarray(w_x[:ndiv], cfg.jdtype()), cfg,
+                        pad_to=self.window_len)
+                else:
+                    res = compress(jnp.asarray(w_x[:ndiv]), cfg)
+                kept = np.asarray(res.kept)
+                xr = np.asarray(res.xr)
+                iters = int(res.iters)
+                if OBS.enabled:
+                    dev = float(res.deviation)
             if ndiv < m:    # kappa-remainder of the final window: verbatim
                 kept = np.concatenate([kept, np.ones(m - ndiv, bool)])
                 xr = np.concatenate([xr, w_x[ndiv:]])
-        else:               # too short for the aggregate math: verbatim
+        else:
             kept = np.ones(m, bool)
             xr = np.asarray(w_x).copy()
             iters = 0
-            verbatim = True
         # global accounting over the kappa-divisible prefix of the stream
         if ndiv:
-            self._orig.append(aggregate_series(
-                np.asarray(w_x[:ndiv], np.float64), cfg.kappa))
-            self._recon.append(aggregate_series(
-                np.asarray(xr[:ndiv], np.float64), cfg.kappa))
+            with span("stream.window.aggregates"):
+                self._orig.append(aggregate_series(
+                    np.asarray(w_x[:ndiv], np.float64), cfg.kappa))
+                self._recon.append(aggregate_series(
+                    np.asarray(xr[:ndiv], np.float64), cfg.kappa))
         w = WindowResult(start=start, x=np.asarray(w_x),
                          kept=kept, xr=xr, n_kept=int(kept.sum()),
                          iters=iters)

@@ -176,6 +176,7 @@ def window_impact_call(cT, cR, dT, s, tab, ny, *, W: int, L: int,
                                measure=measure)
     out = pl.pallas_call(
         kernel,
+        name="acf_window_impact",
         grid=(Pp // LANES,),
         in_specs=window_specs(Hc, dT.shape[0], Lp),
         out_specs=pl.BlockSpec((1, LANES), lambda i: (_zero(), i)),

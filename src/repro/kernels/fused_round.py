@@ -256,6 +256,7 @@ def window_rows_pallas(y, dyws, ystarts, agg_table, ny, *, L: int,
     kernel = functools.partial(_window_rows_kernel, Wy=Wy, Lp=Lp)
     rows = pl.pallas_call(
         kernel,
+        name="window_rows_pallas",
         grid=(Kp // _awi.LANES,),
         in_specs=_awi.window_specs(Hc, dT.shape[0], Lp),
         out_specs=pl.BlockSpec((Lp, _awi.LANES),
@@ -417,6 +418,7 @@ def prefix_devs_pallas(y, dyws, ystarts, ok, agg_table, p0, ny, eps=None, *,
         greedy=greedy)
     out = pl.pallas_call(
         kernel,
+        name="prefix_devs",
         in_specs=[smem, smem, smem, smem, vmem, vmem, vmem],
         out_specs=vmem,
         out_shape=jax.ShapeDtypeStruct((_awi._pad8(-(-K // LANES)), LANES),

@@ -92,6 +92,7 @@ def lag_dot_pallas(y, b=None, halo=None, *, L: int, block: int = 4096,
     kernel = functools.partial(lag_dot_kernel, R=R, Q=Q)
     g = pl.pallas_call(
         kernel,
+        name="lag_dot",
         grid=(nblk,),
         in_specs=[
             pl.BlockSpec((R, LANES), lambda i: (i, _zero())),

@@ -8,12 +8,20 @@ query planner (``query.*``), and span timings (``span.*``) — and
 exports them as a plain dict (:func:`snapshot`) or Prometheus-style
 text (:func:`exposition`).
 
+Spans (:func:`span`) are the program's one tracing system: each is timed
+into the registry and, as a ``jax.profiler.TraceAnnotation`` of its bare
+name, onto the profiler's trace beside the device's operations (see
+:mod:`repro.obs.trace`).  Every span name the program opens is declared
+in :data:`SPANS`; a reader of a trace picks the program's spans out of the
+host plane by that tuple.
+
 Enabling
 --------
 Telemetry is **off by default**.  Set ``CAMEO_OBS=1`` in the
 environment or call :func:`enable` at runtime.  Every instrumented hot
-path is guarded by ``if OBS.enabled:`` so the disabled cost is a single
-attribute lookup (bounded by a microbench in ``tests/test_obs.py``),
+path is guarded by ``if OBS.enabled:`` (or opens ``span(...)``, which
+returns the shared ``NULL_SPAN`` when disabled) so the disabled cost is a
+single attribute lookup (bounded by a microbench in ``tests/test_obs.py``),
 and enabling telemetry changes **no** compressed bytes and **no** query
 answers (differential-tested).  Steady-state ingest overhead with
 telemetry on is gated at <= 3% in ``benchmarks/perf_smoke.py``
@@ -37,20 +45,40 @@ Metric name inventory (the production names; benchmarks reuse them)
 ``store.write.blocks|bytes``       block bodies appended
 ``wal.records`` / ``wal.append_bytes``  write-ahead journal appends
 ``wal.group_commits`` / ``wal.group_batch_records``  fsync barriers / batch size (hist)
-``wal.fsync_seconds``              group-commit fsync latency (hist)
+``wal.fsync_seconds``              group-commit fsync latency (hist, from
+                                   the ``wal.fsync`` span)
 ``wal.checkpoints`` / ``wal.recoveries``  journal truncations / crash recoveries
 ``wal.replayed_records|points``    journaled pushes re-fed on resume
-``ingest.ack_seconds``             façade push journal-ack latency (hist)
+``ingest.ack_seconds``             façade push journal-ack latency (hist,
+                                   from the ``wal.append`` span)
 ``query.count`` / ``query.kind.<agg>`` / ``query.seconds``  query dispatch
+                                   (``query.seconds`` from the ``query`` span)
 ``query.segments_meta|segments_edge``  pushdown-vs-decode block decisions
 ``query.meta_only|with_edge_decode``   per-query decision outcome
 ``query.bound_width``              realized pushdown bound widths (hist)
-``span.<name>.seconds|calls``      user/code spans
+``span.<name>.seconds|calls``      one per name in :data:`SPANS`
 ``server.sessions`` (gauge) / ``server.pushes|points|rejects``  ingest server
+``server.lock_wait_seconds``       time a push waited for the server lock
 ``server.tenant.pushes|points``    per-tenant (labeled ``{tenant="..."}``)
 ``store.tier.cold.hits|bytes``     cold-tier (entropy-wrapped) body fetches
 ``store.compaction.runs|blocks_merged|dead_bytes``  compaction rewrites
 ================================  =====================================
+
+Spans (:data:`SPANS`; nesting on the served push path shown by indent)
+----------------------------------------------------------------------
+==============================  =======================================
+``server.push``                  ``IngestSession.push``, lock wait included
+  ``wal.append``                 the journal record written before the ack
+    ``wal.fsync``                a group-commit barrier (also from flushes)
+  ``stream.window``              the close of one compressed window
+    ``stream.window.rounds``     the rounds program's dispatch and the wait
+                                 for its results (a queued drain's one
+                                 batched dispatch, outside any window span)
+    ``stream.window.aggregates`` the running Eq. 7 aggregates of the window
+  ``store.append``               block planning, encode and write of the
+                                 windows a push closed
+``query``                        one answered pushdown query
+==============================  =======================================
 
 Labels
 ------
@@ -88,20 +116,30 @@ the no-recompile property the perf gates assert.
 from __future__ import annotations
 
 from .registry import MetricsRegistry, StreamingHistogram, sanitize_metric_name
-from .trace import (NULL_SPAN, Span, attach_env_sink, current_span,
-                    emit_event, jsonl_sink, profile)
+from .trace import NULL_SPAN, Span, profile
 
 __all__ = [
-    "OBS", "MetricsRegistry", "StreamingHistogram", "Span", "NULL_SPAN",
-    "enable", "disable", "enabled", "reset", "inc", "gauge", "observe",
-    "span", "event", "add_event_sink", "jsonl_sink", "current_span",
-    "profile", "snapshot", "exposition", "register_jit",
+    "OBS", "SPANS", "MetricsRegistry", "StreamingHistogram", "Span",
+    "NULL_SPAN", "enable", "disable", "enabled", "reset", "inc", "gauge",
+    "observe", "span", "profile", "snapshot", "exposition", "register_jit",
     "recompile_watermark", "recompile_counts", "sanitize_metric_name",
 ]
 
 #: The process-wide registry every instrumented layer records into.
 OBS = MetricsRegistry()
-attach_env_sink(OBS)
+
+#: Every span name the program opens (``tests/test_obs.py`` holds the
+#: program's ``span("...")`` literals to this tuple, both ways).
+SPANS = (
+    "server.push",
+    "wal.append",
+    "wal.fsync",
+    "stream.window",
+    "stream.window.rounds",
+    "stream.window.aggregates",
+    "store.append",
+    "query",
+)
 
 
 def enable():
@@ -120,7 +158,7 @@ def enabled():
 
 
 def reset():
-    """Clear recorded metrics (jit registrations and sinks survive)."""
+    """Clear recorded metrics (jit registrations survive)."""
     OBS.reset()
 
 
@@ -136,25 +174,13 @@ def observe(name, value, labels=None):
     OBS.observe(name, value, labels=labels)
 
 
-def span(name, **attrs):
-    """``with obs.span("stream.push", sid=sid): ...`` — times the block
-    into ``span.<name>.seconds``; nests; no-op when disabled."""
+def span(name):
+    """``with obs.span("stream.window"): ...`` — times the block into
+    ``span.<name>.seconds`` and onto the profiler's trace; the shared
+    ``NULL_SPAN`` when disabled."""
     if not OBS.enabled:
         return NULL_SPAN
-    return Span(OBS, name, attrs)
-
-
-def event(name, **fields):
-    """Emit a structured event to the attached JSONL sinks."""
-    if not OBS.enabled:
-        return
-    emit_event(OBS, dict(fields, ev=name))
-
-
-def add_event_sink(sink):
-    """Attach an event sink (a callable taking one dict, e.g.
-    ``jsonl_sink(path)``)."""
-    OBS._sinks.append(sink)
+    return Span(OBS, name)
 
 
 def snapshot():

@@ -199,7 +199,7 @@ class MetricsRegistry:
     independent instances can be built for tests.  Mutating calls are
     cheap dict operations (no locking on the hot path — CPython's GIL
     makes the worst race a lost increment, acceptable for telemetry);
-    a lock guards structural operations (histogram creation, sinks).
+    a lock guards structural operations (histogram creation).
     """
 
     def __init__(self, enabled=None):
@@ -208,7 +208,6 @@ class MetricsRegistry:
         self._gauges = {}
         self._hists = {}
         self._jits = {}
-        self._sinks = []
         self._lock = threading.Lock()
 
     # -- recording ---------------------------------------------------------
@@ -246,8 +245,8 @@ class MetricsRegistry:
         self.enabled = False
 
     def reset(self):
-        """Clear recorded metrics.  Jit registrations and sinks survive:
-        they describe process structure, not accumulated measurements."""
+        """Clear recorded metrics.  Jit registrations survive: they
+        describe process structure, not accumulated measurements."""
         self._counters.clear()
         self._gauges.clear()
         with self._lock:

@@ -1,36 +1,42 @@
-"""Span tracing, structured JSONL events, and the ``profile()`` bracket.
+"""Spans on the profiler's clock, and the ``profile()`` bracket.
 
-Spans are lightweight context managers recording wall-clock duration
-into the registry (``span.<name>.seconds`` histogram plus a
-``span.<name>.calls`` counter) and, when an event sink is attached,
-emitting one structured event per span with nesting depth, parent span
-name, and per-span attrs.  When the registry is disabled,
-``span(...)`` returns a shared no-op object — no allocation, no timer.
+A span times a block of host code with one pair of clock reads.  The
+duration goes to the registry (a ``span.<name>.seconds`` histogram and a
+``span.<name>.calls`` counter) and stays on the span as ``seconds``, so a
+layer whose own histogram covers the same interval observes it from there
+instead of reading the clock again.  The span also opens a
+``jax.profiler.TraceAnnotation`` of its bare name: under a profiler
+session it lands on the host plane of the trace, on the same clock as the
+device's operations, nested by time in the spans around it; with no
+session active the annotation is a no-op inside the profiler's C++
+``TraceMe``.  When the registry is disabled, ``span(...)`` returns a
+shared no-op object: no allocation, no timer, no annotation.
 
-Event sinks are callables taking one dict; ``jsonl_sink(path)`` adapts
-a file path.  Setting ``CAMEO_OBS_EVENTS=<path>`` in the environment
-attaches a JSONL file sink to the process-wide registry at import.
-
-``profile(logdir)`` is the opt-in ``jax.profiler`` bracket for TPU/CPU
-trace capture; it imports jax lazily so the obs package itself stays
-dependency-free.
+jax is imported on the first span entered (a process that enables
+telemetry has it loaded already), so ``repro.obs`` itself imports without
+it.  ``profile(logdir)`` is the opt-in ``jax.profiler`` bracket for
+TPU/CPU trace capture.
 """
 from __future__ import annotations
 
 import contextlib
-import json
 import os
-import threading
 import time
 
-_TLS = threading.local()
+_ANNOTATION = None
 
 
-def _stack():
-    s = getattr(_TLS, "spans", None)
-    if s is None:
-        s = _TLS.spans = []
-    return s
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported once; a null context where
+    jax is not installed."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = contextlib.nullcontext
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
 
 
 class _NullSpan:
@@ -44,92 +50,31 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
-    def set(self, key, value):
-        return self
-
 
 NULL_SPAN = _NullSpan()
 
 
 class Span:
-    __slots__ = ("name", "attrs", "registry", "t0", "depth", "parent")
+    __slots__ = ("registry", "name", "seconds", "_t0", "_ann")
 
-    def __init__(self, registry, name, attrs):
+    def __init__(self, registry, name):
         self.registry = registry
         self.name = name
-        self.attrs = attrs
-        self.t0 = 0.0
-        self.depth = 0
-        self.parent = None
-
-    def set(self, key, value):
-        """Attach/overwrite an attr mid-span."""
-        self.attrs[key] = value
-        return self
+        self.seconds = 0.0
 
     def __enter__(self):
-        stack = _stack()
-        self.depth = len(stack)
-        self.parent = stack[-1].name if stack else None
-        stack.append(self)
-        self.t0 = time.perf_counter()
+        self._ann = _annotation()(self.name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dt = time.perf_counter() - self.t0
-        stack = _stack()
-        if stack and stack[-1] is self:
-            stack.pop()
+        self.seconds = time.perf_counter() - self._t0
+        self._ann.__exit__(exc_type, exc, tb)
         reg = self.registry
-        reg.observe(f"span.{self.name}.seconds", dt)
+        reg.observe(f"span.{self.name}.seconds", self.seconds)
         reg.inc(f"span.{self.name}.calls")
-        if reg._sinks:
-            ev = {"ev": "span", "name": self.name, "dur_s": dt,
-                  "depth": self.depth, "parent": self.parent}
-            if exc_type is not None:
-                ev["error"] = exc_type.__name__
-            if self.attrs:
-                ev["attrs"] = self.attrs
-            emit_event(reg, ev)
         return False
-
-
-def current_span():
-    """The innermost active span on this thread, or None."""
-    s = _stack()
-    return s[-1] if s else None
-
-
-def jsonl_sink(path):
-    """An event sink appending one JSON object per line to ``path``."""
-    lock = threading.Lock()
-
-    def sink(ev):
-        line = json.dumps(ev, sort_keys=True, default=str)
-        with lock:
-            with open(path, "a") as f:
-                f.write(line + "\n")
-
-    sink.path = path
-    return sink
-
-
-def emit_event(registry, ev):
-    """Deliver one structured event dict to every attached sink."""
-    if "ts" not in ev:
-        ev = dict(ev, ts=time.time())
-    for sink in registry._sinks:
-        try:
-            sink(ev)
-        except Exception:
-            pass  # telemetry must never take down the data path
-
-
-def attach_env_sink(registry):
-    """Honor ``CAMEO_OBS_EVENTS=<path>`` by attaching a JSONL sink."""
-    path = os.environ.get("CAMEO_OBS_EVENTS", "").strip()
-    if path:
-        registry._sinks.append(jsonl_sink(path))
 
 
 @contextlib.contextmanager

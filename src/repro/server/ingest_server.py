@@ -37,12 +37,13 @@ from __future__ import annotations
 
 import dataclasses
 import threading
+from time import perf_counter as _perf_counter
 from typing import Dict, Optional, Tuple
 
 import numpy as np
 
 from repro.api.dataset import Dataset, DatasetView, Series, StreamWriter
-from repro.obs import OBS
+from repro.obs import OBS, span
 from repro.server.catalog import DEFAULT_TENANT, TenantCatalog, tenant_sid
 from repro.server.compaction import CompactionWorker
 from repro.server.tiers import TierManager
@@ -131,13 +132,20 @@ class ServerSession:
         """Feed a chunk (journaled-before-ack; see ``StreamWriter.push``).
         Raises :class:`QuotaExceeded` *before* journaling when the chunk
         would take the tenant past its quota."""
+        with span("server.push"):
+            return self._push(chunk)
+
+    def _push(self, chunk) -> int:
         if self.closed:
             raise ValueError(f"session {self.tenant!r}/{self.series!r} "
                              "is closed")
         chunk = np.asarray(chunk)
         m = int(chunk.size)           # channel-expanded points
         srv = self._server
+        t0 = _perf_counter() if OBS.enabled else 0.0
         with srv._lock:
+            if OBS.enabled:
+                OBS.observe("server.lock_wait_seconds", _perf_counter() - t0)
             if self._quota is not None:
                 used = srv._used_points.get(self.tenant, 0)
                 if used + m > self._quota:

@@ -34,11 +34,9 @@ reconstructions without touching the bitstreams, which is the steady-state
 """
 from __future__ import annotations
 
-from time import perf_counter as _perf_counter
-
 import numpy as np
 
-from repro.obs import OBS
+from repro.obs import NULL_SPAN, OBS, span
 
 _U = 2.0 ** -52          # one ulp at 1.0
 _SLOP = 64.0             # growth allowance on accumulated rounding
@@ -288,11 +286,11 @@ def query(store, sid: str, kind: str, a=None, b=None, col=None):
     column projects from the same ``MBlockMeta``), returning stacked
     ``(values [C, ...], bounds [C, ...])`` arrays.
     """
-    if not OBS.enabled:
-        return _query(store, sid, kind, a, b, col)
-    t0 = _perf_counter()
-    out = _query(store, sid, kind, a, b, col)
-    OBS.observe("query.seconds", _perf_counter() - t0)
+    with span("query") as sp:
+        out = _query(store, sid, kind, a, b, col)
+    if sp is NULL_SPAN:
+        return out
+    OBS.observe("query.seconds", sp.seconds)
     OBS.inc("query.count")
     OBS.inc(f"query.kind.{kind}")
     # realized bound width: the widest bound the answer shipped with

@@ -78,7 +78,7 @@ from typing import List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
-from ..obs import OBS
+from ..obs import NULL_SPAN, OBS, span
 
 MAGIC = b"CAMEOWAL\x01"
 _REC = struct.Struct("<II")          # payload length, crc32(payload)
@@ -384,11 +384,11 @@ class WriteAheadLog:
         if not self._unsynced_records:
             return
         batch = self._unsynced_records
-        t0 = time.perf_counter()
-        maybe_fsync(self._f)
-        if OBS.enabled:
+        with span("wal.fsync") as sp:
+            maybe_fsync(self._f)
+        if sp is not NULL_SPAN:
             OBS.inc("wal.group_commits")
-            OBS.observe("wal.fsync_seconds", time.perf_counter() - t0)
+            OBS.observe("wal.fsync_seconds", sp.seconds)
             OBS.observe("wal.group_batch_records", float(batch))
         self._unsynced_bytes = 0
         self._unsynced_records = 0

@@ -7,7 +7,8 @@ What is pinned here:
 * the disabled path costs one attribute lookup — a microbench bounds it,
   and ``obs.span`` returns the shared ``NULL_SPAN`` identity;
 * the Prometheus-style exposition is byte-deterministic (golden test);
-* span nesting depth / parent attribution / attrs via the JSONL sink;
+* spans land on the profiler's trace by their bare names, nested by time,
+  and in the registry; every span the program opens is in ``obs.SPANS``;
 * the observer property: ingesting and querying with ``CAMEO_OBS`` on
   produces **byte-identical stores and bit-identical query answers** to
   running with it off;
@@ -21,9 +22,12 @@ What is pinned here:
   query session reports push-latency quantiles, window/queue counters,
   the recompile watermark, cache hit rates, and realized bound widths.
 """
-import json
+import glob
 import math
 import os
+import re
+import subprocess
+import sys
 import time
 
 import numpy as np
@@ -49,13 +53,11 @@ def _series(n, seed=0):
 def obs_state():
     """Reset the process-wide registry on entry (a CAMEO_OBS=1 suite run
     accumulates metrics from every preceding test) and restore the
-    enabled flag + sinks on exit, so suite runs with CAMEO_OBS=1 and =0
-    both stay hermetic."""
+    enabled flag on exit, so suite runs with CAMEO_OBS=1 and =0 both stay
+    hermetic."""
     was = obs.enabled()
-    sinks = list(OBS._sinks)
     obs.reset()
     yield OBS
-    OBS._sinks[:] = sinks
     obs.reset()
     OBS.enabled = was
 
@@ -115,11 +117,12 @@ def test_sanitize_metric_name():
 
 def test_disabled_span_is_shared_noop(obs_state):
     obs.disable()
-    s = obs.span("anything", k=1)
+    s = obs.span("anything")
     assert s is NULL_SPAN
     with s as inner:
-        inner.set("x", 2)            # no-op, chainable
+        assert inner is NULL_SPAN
     assert obs.snapshot()["counters"] == {}
+    assert obs.snapshot()["histograms"] == {}
 
 
 def test_disabled_path_microbench(obs_state):
@@ -150,6 +153,24 @@ def test_disabled_path_microbench(obs_state):
     assert per_iter < 2e-6, f"disabled guard costs {per_iter * 1e9:.0f}ns"
     assert g < 20 * max(b, 1e-9) + 1e-3
     assert obs.snapshot()["counters"] == {}
+
+
+def test_disabled_span_site_microbench(obs_state):
+    """A ``with obs.span(...)`` site costs one call and the shared no-op's
+    enter/exit when disabled: no timer, no annotation, no allocation."""
+    obs.disable()
+    n = 100_000
+
+    def site():
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with obs.span("server.push"):
+                pass
+        return time.perf_counter() - t0
+
+    per_iter = min(site() for _ in range(5)) / n
+    assert per_iter < 2e-6, f"disabled span costs {per_iter * 1e9:.0f}ns"
+    assert obs.snapshot()["histograms"] == {}
 
 
 # ---------------------------------------------------------------------------
@@ -229,14 +250,15 @@ def test_registry_reset_keeps_structure():
     reg = MetricsRegistry(enabled=True)
     with pytest.raises(TypeError):
         reg.register_jit("plain", lambda: None)
-    seen = []
-    reg._sinks.append(seen.append)
+    import jax
+    jitted = jax.jit(lambda v: v + 1)
+    reg.register_jit("plain", jitted)
     reg.inc("c")
     reg.observe("h", 1.0)
     reg.reset()
     snap = reg.snapshot()
     assert snap["counters"] == {} and snap["histograms"] == {}
-    assert reg._sinks == [seen.append]          # sinks survive reset
+    assert snap["recompiles"]["entries"] == {"plain": 0}   # jits survive
 
 
 # ---------------------------------------------------------------------------
@@ -244,44 +266,74 @@ def test_registry_reset_keeps_structure():
 # ---------------------------------------------------------------------------
 
 def test_span_nesting_attrs_jsonl(obs_state, tmp_path):
-    path = str(tmp_path / "events.jsonl")
+    """A span opened under a profiler session lands on the trace's host
+    plane by its bare name, nested by time inside the span around it, and
+    records its time into the registry and on the span (a span left by an
+    exception too)."""
+    import jax
+    from jax.profiler import ProfileData
     obs.enable()
     obs.reset()
-    OBS._sinks[:] = [obs.jsonl_sink(path)]
-    with obs.span("outer", sid="s1"):
-        assert obs.current_span().name == "outer"
-        with obs.span("inner") as sp:
-            sp.set("rows", 7)
-            assert sp.depth == 1 and sp.parent == "outer"
-    with pytest.raises(ValueError):
-        with obs.span("boom"):
-            raise ValueError("x")
-    evs = [json.loads(line) for line in open(path)]
-    by_name = {e["name"]: e for e in evs}
-    assert by_name["inner"]["depth"] == 1
-    assert by_name["inner"]["parent"] == "outer"
-    assert by_name["inner"]["attrs"] == {"rows": 7}
-    assert by_name["outer"]["depth"] == 0 and by_name["outer"]["parent"] is None
-    assert by_name["boom"]["error"] == "ValueError"
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with obs.span("stream.window") as outer:
+            with obs.span("stream.window.rounds") as inner:
+                time.sleep(0.002)
+        with pytest.raises(ValueError):
+            with obs.span("query"):
+                raise ValueError("x")
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = {e.name: (e.start_ns, e.end_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name in obs.SPANS}
+    assert set(host) == {"stream.window", "stream.window.rounds", "query"}
+    (s0, e0), (s1, e1) = host["stream.window"], host["stream.window.rounds"]
+    assert s0 <= s1 < e1 <= e0
+    assert e1 - s1 >= 2e6 and host["query"][0] >= e0
     snap = obs.snapshot()
-    assert snap["counters"]["span.outer.calls"] == 1
-    assert snap["histograms"]["span.inner.seconds"]["count"] == 1
-    assert all(e["ts"] > 0 for e in evs)
+    assert snap["counters"]["span.stream.window.calls"] == 1
+    assert snap["counters"]["span.query.calls"] == 1
+    h = snap["histograms"]["span.stream.window.rounds.seconds"]
+    assert h["count"] == 1 and h["sum"] == inner.seconds >= 0.002
+    assert outer.seconds >= inner.seconds
 
 
-def test_event_api_and_sink_errors_are_swallowed(obs_state):
-    obs.enable()
-    got = []
+def test_spans_declared_and_opened():
+    """Every span name in ``obs.SPANS`` is opened somewhere in the
+    program, and every span the program opens is declared there."""
+    pkg = os.path.dirname(os.path.dirname(obs.__file__))
+    opened = set()
+    for root, _, files in os.walk(pkg):
+        if os.path.basename(root) == "obs":
+            continue                 # the telemetry layer's own docstrings
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(root, f)) as fh:
+                    opened |= set(re.findall(r'\bspan\(\s*"([^"]+)"',
+                                             fh.read()))
+    assert len(obs.SPANS) == len(set(obs.SPANS))
+    assert opened == set(obs.SPANS)
 
-    def bad_sink(ev):
-        raise RuntimeError("sink down")
 
-    OBS._sinks[:] = [bad_sink, got.append]
-    obs.event("checkpoint", step=3)             # must not raise
-    assert got and got[0]["ev"] == "checkpoint" and got[0]["step"] == 3
-    obs.disable()
-    obs.event("dropped")
-    assert len(got) == 1
+def test_obs_spans_without_jax():
+    """``repro.obs`` imports and times spans in a process without jax
+    (the annotation falls back to a null context)."""
+    src = os.path.dirname(os.path.dirname(os.path.dirname(obs.__file__)))
+    code = ("import sys; sys.modules['jax'] = None\n"
+            "import repro.obs as o\n"
+            "o.enable()\n"
+            "with o.span('query') as sp: pass\n"
+            "assert o.snapshot()['counters']['span.query.calls'] == 1\n"
+            "assert 'jax' not in [m for m in sys.modules if sys.modules[m]]\n")
+    env = dict(os.environ, PYTHONPATH=src)
+    subprocess.run([sys.executable, "-c", code], check=True, env=env,
+                   timeout=60)
 
 
 # ---------------------------------------------------------------------------
@@ -337,16 +389,41 @@ def _ingest_and_query(path):
     return out, stats
 
 
+def _serve_and_query(path):
+    """One served session pushed 48 points at a time, so that windows
+    close inside pushes (the spans of the served path: server push, journal,
+    window close, rounds, aggregates, store append), then dashboard
+    queries through the server's view."""
+    from repro.server.catalog import DEFAULT_TENANT
+    from repro.server.ingest_server import IngestServer, ServerConfig
+
+    x = _series(1536, seed=13)
+    srv = IngestServer(path, CFG, ServerConfig(
+        block_len=256, stream_window=256, auto_compact=False))
+    with srv.session("feed") as sess:
+        for lo in range(0, len(x), 48):
+            sess.push(x[lo:lo + 48])
+    s = srv.view(DEFAULT_TENANT).series("feed")
+    out = dict(srv_mean=s.mean(100, 1400), srv_var=s.var(),
+               srv_acf=s.acf(0, 1024), srv_win=s.window(200, 700))
+    srv.close()
+    return out
+
+
 def test_obs_on_off_differential(obs_state, tmp_path):
     p_off, p_on = str(tmp_path / "off.cameo"), str(tmp_path / "on.cameo")
+    s_off, s_on = str(tmp_path / "s_off.cameo"), str(tmp_path / "s_on.cameo")
     obs.disable()
     out_off, stats_off = _ingest_and_query(p_off)
+    out_off.update(_serve_and_query(s_off))
     obs.enable()
     obs.reset()
     out_on, stats_on = _ingest_and_query(p_on)
-    with open(p_off, "rb") as f1, open(p_on, "rb") as f2:
-        assert f1.read() == f2.read(), \
-            "enabling telemetry changed the stored bytes"
+    out_on.update(_serve_and_query(s_on))
+    for off, on in ((p_off, p_on), (s_off, s_on)):
+        with open(off, "rb") as f1, open(on, "rb") as f2:
+            assert f1.read() == f2.read(), \
+                "enabling telemetry changed the stored bytes"
     for k in out_off:
         a, b = out_off[k], out_on[k]
         if isinstance(a, tuple):
@@ -362,6 +439,11 @@ def test_obs_on_off_differential(obs_state, tmp_path):
     snap = obs.snapshot()
     assert snap["counters"]["stream.windows"] >= 6
     assert snap["histograms"]["stream.push_seconds"]["count"] >= 1
+    # with every span of the program opened on the way
+    for name in obs.SPANS:
+        assert snap["counters"][f"span.{name}.calls"] >= 1, name
+    assert snap["histograms"]["server.lock_wait_seconds"]["count"] == 32
+    assert snap["counters"]["span.server.push.calls"] == 32
 
 
 # ---------------------------------------------------------------------------

@@ -569,7 +569,6 @@ def test_metrics_endpoint_serves_labeled_exposition(tmp_path):
     import repro.obs as obs
     from repro.obs import OBS
     was = obs.enabled()
-    sinks = list(OBS._sinks)
     obs.reset()
     obs.enable()
     try:
@@ -599,6 +598,5 @@ def test_metrics_endpoint_serves_labeled_exposition(tmp_path):
         assert seen["status"].startswith("404") and b404
         srv.close()
     finally:
-        OBS._sinks[:] = sinks
         obs.reset()
         OBS.enabled = was

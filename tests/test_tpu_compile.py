@@ -12,6 +12,7 @@ in a ``skipif`` or in a ``parametrize``): only one process at a time may
 load the TPU library, and only the test worker that runs this file does.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -127,7 +128,10 @@ def test_rounds_program_compiles_with_kernel(spec, no_persistent_cache,
                                              monkeypatch, dtype, backend):
     """The whole rounds program at the uk_elec bucket: every kernel at
     float32 with ``backend="pallas"``, and the default float64
-    configuration, whose ranking kernel runs at float32."""
+    configuration, whose ranking kernel runs at float32.  The compiled text
+    carries the round's phase scopes in its ``op_name`` metadata, and the
+    ranking kernel's custom call keeps the instruction name the trace's
+    readers key on."""
     monkeypatch.delenv("CAMEO_BACKEND", raising=False)
     monkeypatch.delenv("CAMEO_FORCE_INTERPRET", raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -140,4 +144,10 @@ def test_rounds_program_compiles_with_kernel(spec, no_persistent_cache,
     compiled = cameo._rounds_padded.lower(
         spec((nb,), dt), spec((), I32), spec((), I32), spec((), dt),
         cfg=cfg).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    scopes = {part for path in re.findall(r'op_name="([^"]*)"', text)
+              for part in path.split("/")[:-1]}
+    assert {"rank", "select", "update"} <= scopes
+    assert re.search(r'^\s*%window_rows_pallas(\.\d+)? = .*custom-call\(.*'
+                     r'custom_call_target="tpu_custom_call"', text, re.M)
