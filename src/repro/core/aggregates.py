@@ -47,8 +47,8 @@ def apply_delta_dense(agg, y_old: jax.Array, delta: jax.Array, ny=None,
 
     ``y_old`` is the reconstruction *before* the update.  Cost: O(ny + L) for
     the four moment sums (via cumulative sums) + one ``[ny] x [ny, L]``
-    contraction for ``sxx`` (lag shifts gathered against a constant shift
-    basis — no per-lag op chains).
+    contraction for ``sxx`` (against a lag-shift basis built without an
+    index array — no per-lag op chains, no gather).
 
     ``agg`` may be the ``Aggregates`` NamedTuple or the packed ``[5, L]``
     moment table (the rounds-mode loop carry); the update comes back in the
@@ -57,10 +57,10 @@ def apply_delta_dense(agg, y_old: jax.Array, delta: jax.Array, ny=None,
     ``ny`` (optionally traced) gives the valid length when ``y_old``/``delta``
     live in a zero-padded bucket; both must be zero beyond it.
 
-    ``form`` picks the bilinear-term lowering: ``"gather"`` (two matvecs
-    against the [nyb, L] shift basis), ``"roll"`` (one batched
-    roll-and-reduce over the lag axis), or ``"auto"`` (roll on CPU, gather
-    elsewhere — see the comment at the term).
+    ``form`` picks the bilinear-term lowering: ``"slices"`` (two matvecs
+    against the [nyb, L] shift basis of ``ref.shift_basis``), ``"roll"``
+    (one batched roll-and-reduce over the lag axis), or ``"auto"`` (roll on
+    CPU, slices elsewhere — see the comment at the term).
     """
     nyb = y_old.shape[0]
     if ny is None:
@@ -82,14 +82,16 @@ def apply_delta_dense(agg, y_old: jax.Array, delta: jax.Array, ny=None,
     #   d_t*y_{t+l} + y_t*d_{t+l} + d_t*d_{t+l}
     #     = d_t*(y+d)_{t+l} + y_t*d_{t+l}
     # Backend-conditional trace-time form (parity-tested in
-    # tests/test_contractions.py): XLA's CPU emitter runs both the [nyb, L]
-    # shift-basis gather and a per-lag chain of 2L small dots an order of
-    # magnitude slower than one batched roll+mask+reduce (the gather takes
-    # the slow general-gather path; the dot chain is dispatch-bound).
-    # Elsewhere the gathered basis keeps the whole term at
-    # two matvecs against a [nyb, L] operand — matmul-shaped for the MXU.
+    # tests/test_contractions.py): XLA's CPU emitter runs a per-lag chain
+    # of small dots or a [nyb, L] shift basis an order of magnitude slower
+    # than one batched roll+mask+reduce.  On a TPU v5e an index gather of
+    # that basis ran under 1 GB/s (about 1.4 ms for each [4096, 48]
+    # emulated-f64 operand, over half of every round), and the vmapped
+    # roll lowers to the same gather.  So there the basis comes from
+    # ref.shift_basis (a reshape and static slices, no index array), and
+    # the term is two matvecs against it.
     if form == "auto":
-        form = "roll" if jax.default_backend() == "cpu" else "gather"
+        form = "roll" if jax.default_backend() == "cpu" else "slices"
     if form == "roll":
         z = y_old + delta
         t = jnp.arange(nyb)
@@ -102,12 +104,11 @@ def apply_delta_dense(agg, y_old: jax.Array, delta: jax.Array, ny=None,
                                    + y_old * jnp.roll(delta, -ll)))
 
         dsxx = jax.vmap(lag_term)(l)
+    elif form == "slices":
+        dsxx = (delta @ _ref.shift_basis(y_old + delta, L)
+                + y_old @ _ref.shift_basis(delta, L))
     else:
-        z_pad = jnp.pad(y_old + delta, (0, L))
-        d_pad = jnp.pad(delta, (0, L))
-        t = jnp.arange(nyb)
-        shift = t[:, None] + l[None, :]                   # [nyb, L]
-        dsxx = delta @ z_pad[shift] + y_old @ d_pad[shift]
+        raise ValueError(f"unknown form {form!r}")
 
     dtable = jnp.stack([dsx, dsxl, dsx2, dsxl2, dsxx])
     if isinstance(agg, jax.Array):

@@ -8,6 +8,10 @@ property tests can pin the algebra across lag depths, aggregation factors
 and deviation measures.  Tolerances are float64-tight: the contraction and
 the loop differ only in reduction order.
 """
+import functools
+import re
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -115,10 +119,10 @@ def test_lag_xdot_matches_slice_oracle(seed, L):
 @given(st.integers(0, 10_000), _L, _KAPPA)
 def test_apply_delta_dense_matches_roll_oracle(seed, L, kappa):
     """aggregates.apply_delta_dense (Eq. 10/11) ≡ apply_delta_dense_ref
-    (per-lag roll-mask-sum oracle) for both bilinear lowerings — "gather"
-    ([nyb, L] shift basis, the accelerator form) and "roll" (batched
-    roll-and-reduce, the CPU form) — in both the NamedTuple and
-    packed-table carry forms, under padded buckets."""
+    (per-lag roll-mask-sum oracle) for both bilinear lowerings — "slices"
+    ([nyb, L] shift basis with no index array, the accelerator form) and
+    "roll" (batched roll-and-reduce, the CPU form) — in both the
+    NamedTuple and packed-table carry forms, under padded buckets."""
     y, ny, rng = _target_series(seed, 96, kappa)
     agg = extract_aggregates(y[:ny], L)
     delta = np.zeros(y.shape[0])
@@ -127,7 +131,7 @@ def test_apply_delta_dense_matches_roll_oracle(seed, L, kappa):
     delta = jnp.asarray(delta)
     oracle = apply_delta_dense_ref(agg, y, delta, ny=ny)
     table = jnp.stack(list(agg))
-    for form in ("gather", "roll"):
+    for form in ("slices", "roll"):
         new = apply_delta_dense(agg, y, delta, ny=ny, form=form)
         for got, want in zip(new, oracle):
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -168,11 +172,38 @@ def test_bilinear_forms_parity_deterministic(L, kappa):
     delta = jnp.asarray(delta)
     oracle = jnp.stack(list(apply_delta_dense_ref(agg, y, delta, ny=ny)))
     table = jnp.stack(list(agg))
-    for form in ("gather", "roll"):
+    for form in ("slices", "roll"):
         got_t = apply_delta_dense(table, y, delta, ny=ny, form=form)
         np.testing.assert_allclose(np.asarray(got_t), np.asarray(oracle),
                                    rtol=1e-11, atol=1e-11,
                                    err_msg=f"form={form}")
+
+
+@pytest.mark.parametrize("n,L", [(96, 48), (130, 4), (4096, 48),
+                                 (3840, 365)])
+def test_shift_basis_matches_index_basis(n, L):
+    """ref.shift_basis (no index array) is exactly the index-gathered
+    basis ``v_pad[t + l]`` — the rounds-program widths among the cases,
+    and L = 365 (min_temp) past the series end."""
+    v = np.random.default_rng(n + L).standard_normal(n)
+    want = np.pad(v, (0, L))[np.arange(n)[:, None]
+                             + np.arange(1, L + 1)[None, :]]
+    got = np.asarray(ref.shift_basis(jnp.asarray(v), L))
+    assert got.shape == (n, L)
+    assert np.array_equal(got, want)
+
+
+def test_dense_update_slices_form_has_no_basis_gather():
+    """The accelerator form of the dense update lowers with no gather of
+    rank 2 (the [nyb, L] basis) at the served window's width, L = 48,
+    float64."""
+    nyb, L = 4096, 48
+    text = jax.jit(functools.partial(apply_delta_dense, form="slices")).lower(
+        jax.ShapeDtypeStruct((5, L), jnp.float64),
+        jax.ShapeDtypeStruct((nyb,), jnp.float64),
+        jax.ShapeDtypeStruct((nyb,), jnp.float64)).as_text()
+    gathers = re.findall(r'"stablehlo\.gather".*-> tensor<([^>]*)>', text)
+    assert not [g for g in gathers if g.count("x") == 2], gathers
 
 
 @pytest.mark.parametrize("L", [4, 12])

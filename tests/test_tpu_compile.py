@@ -12,6 +12,7 @@ in a ``skipif`` or in a ``parametrize``): only one process at a time may
 load the TPU library, and only the test worker that runs this file does.
 """
 import functools
+import math
 import re
 
 import jax
@@ -131,7 +132,8 @@ def test_rounds_program_compiles_with_kernel(spec, no_persistent_cache,
     configuration, whose ranking kernel runs at float32.  The compiled text
     carries the round's phase scopes in its ``op_name`` metadata, and the
     ranking kernel's custom call keeps the instruction name the trace's
-    readers key on."""
+    readers key on.  The dense update builds its ``[nb, L]`` lag-shift
+    basis without a gather (one ran under 1 GB/s on the chip)."""
     monkeypatch.delenv("CAMEO_BACKEND", raising=False)
     monkeypatch.delenv("CAMEO_FORCE_INTERPRET", raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -151,3 +153,9 @@ def test_rounds_program_compiles_with_kernel(spec, no_persistent_cache,
     assert {"rank", "select", "update"} <= scopes
     assert re.search(r'^\s*%window_rows_pallas(\.\d+)? = .*custom-call\(.*'
                      r'custom_call_target="tpu_custom_call"', text, re.M)
+    basis_gathers = [
+        line for line in text.splitlines()
+        if (g := re.search(r'= \w+\[([\d,]+)\]\S* gather\(', line))
+        and math.prod(map(int, g.group(1).split(","))) == nb * L
+        and re.search(r'op_name="[^"]*/update/', line)]
+    assert not basis_gathers, basis_gathers[:2]
