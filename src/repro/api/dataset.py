@@ -38,9 +38,9 @@ from __future__ import annotations
 import dataclasses
 import math
 import os
-from time import perf_counter as _perf_counter
 from typing import Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro.core.cameo import (
@@ -219,7 +219,6 @@ class Series:
 
 
 def _pacf_with_bound(r: np.ndarray, r_bound: np.ndarray):
-    import jax
     import jax.numpy as jnp
 
     from repro.core.acf import pacf_from_acf
@@ -521,15 +520,18 @@ class Dataset:
                     "per-column eps budgets need a 2-D [n, C] series")
         if x.ndim not in (1, 2):
             raise ValueError(f"series must be [n] or [n, C], got {x.shape}")
-        t0 = _perf_counter() if OBS.enabled else 0.0
-        if x.ndim == 1:
-            res = compress(x, cfg)
-        else:
-            res = compress_multivariate(x, cfg, eps_c=eps_c)
-        entry = self._store.append_series(
-            sid, res, cfg, x=x if self.store_residuals else None)
-        if OBS.enabled:
-            OBS.observe("write.seconds", _perf_counter() - t0)
+        with span("dataset.write") as sp:
+            with span("dataset.write.compress"):
+                if x.ndim == 1:
+                    res = compress(x, cfg)
+                else:
+                    res = compress_multivariate(x, cfg, eps_c=eps_c)
+                # the span holds the device's time, not just the dispatch
+                jax.block_until_ready(res)
+            entry = self._store.append_series(
+                sid, res, cfg, x=x, with_resid=self.store_residuals)
+        if sp is not NULL_SPAN:
+            OBS.observe("write.seconds", sp.seconds)
             OBS.observe("write.rounds", int(res.iters))
             OBS.inc("write.series")
             devs = np.atleast_1d(entry.get("deviations", entry["deviation"]))
@@ -545,8 +547,6 @@ class Dataset:
         equal-length groups through ``compress_batch`` (one compile, B
         series; per-series results bit-identical to solo runs)."""
         self._require_write()
-        import jax
-
         groups: Dict[int, List] = {}
         for sid, x in items.items():
             x = np.asarray(x)
@@ -569,8 +569,8 @@ class Dataset:
                        for i in range(len(group))]
             for (sid, x), r in zip(group, per):
                 out[sid] = self._store.append_series(
-                    sid, r, self.cfg,
-                    x=x if self.store_residuals else None)
+                    sid, r, self.cfg, x=x,
+                    with_resid=self.store_residuals)
         return out
 
     def stream(self, sid: str, *, window_len: int = None, channels: int = 1,

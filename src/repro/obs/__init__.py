@@ -64,8 +64,8 @@ Metric name inventory (the production names; benchmarks reuse them)
 ``store.compaction.runs|blocks_merged|dead_bytes``  compaction rewrites
 ================================  =====================================
 
-Spans (:data:`SPANS`; nesting on the served push path shown by indent)
-----------------------------------------------------------------------
+Spans (:data:`SPANS`; nesting shown by indent)
+----------------------------------------------
 ==============================  =======================================
 ``server.push``                  ``IngestSession.push``, lock wait included
   ``wal.append``                 the journal record written before the ack
@@ -78,6 +78,11 @@ Spans (:data:`SPANS`; nesting on the served push path shown by indent)
   ``store.append``               block planning, encode and write of the
                                  windows a push closed
 ``query``                        one answered pushdown query
+``dataset.write``                ``Dataset.write``, one one-shot archive write
+  ``dataset.write.compress``     the compressor's dispatch and the wait for
+                                 its results
+  ``store.append_series``        block planning, encode and write of the
+                                 compressed series
 ==============================  =======================================
 
 Labels
@@ -139,6 +144,9 @@ SPANS = (
     "stream.window.aggregates",
     "store.append",
     "query",
+    "dataset.write",
+    "dataset.write.compress",
+    "store.append_series",
 )
 
 

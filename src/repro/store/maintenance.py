@@ -59,9 +59,7 @@ def _check_rewritable(store, sid: str) -> dict:
 def _finish(store, sid: str) -> None:
     """Publish a rewrite: drop stale decoded state, then flush the footer
     (the atomic commit point — see module docstring)."""
-    store._cache.invalidate(sid)
-    for key in [k for k in store._metas if k[0] == sid]:
-        del store._metas[key]
+    store._forget(sid)
     store.flush()
 
 

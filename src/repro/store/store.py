@@ -80,8 +80,16 @@ are page-cache slices with no syscalls at all (``CAMEO_MMAP=0`` or
 platforms without usable mmap fall back to the pread path — results are
 byte-identical either way).
 
+One writer thread and any number of reader threads may share a store:
+body reads and appends take the store's I/O lock (``_io``), since both
+move the one file handle and an append makes the mmap view stale.  The
+lock covers the seeks, reads and writes alone: decoding a fetched body and
+the fsync barriers run outside it.  Reads served from the block cache take
+only the cache's own lock.
+
 Roundtrip contract (tested property-style): for any compressed series,
-``read_kept`` reproduces the kept mask and kept values bit-exactly, and
+``read_kept`` reproduces the kept mask and kept values bit-exactly (the
+written points, where ``append_series`` is handed them), and
 ``read_series``/``read_window`` reproduce the canonical reconstruction —
 the one-shot interpolation of the kept points — **bit-exactly**.  For the
 rounds mode that canonical form *is* ``CompressResult.xr``; see
@@ -94,12 +102,13 @@ import collections
 import json
 import os
 import struct
+import threading
 import zlib
 from typing import Dict, List
 
 import numpy as np
 
-from repro.obs import OBS
+from repro.obs import OBS, span
 from repro.store import codec as _codec
 from repro.store import wal as _wal
 from repro.store.blocks import (
@@ -150,10 +159,12 @@ class BlockCache:
     blocks of latency-critical windows; see ``server/tiers.py``).  When
     every entry is pinned the cache is allowed to run over budget rather
     than evict a pin — unpinning re-triggers eviction on the next put.
+
+    Reader threads share it, so each operation holds the cache's own lock.
     """
 
     __slots__ = ("budget", "nbytes", "hits", "misses", "evictions", "_d",
-                 "_pinned")
+                 "_pinned", "_lock")
 
     def __init__(self, budget: int):
         self.budget = int(budget)
@@ -163,64 +174,73 @@ class BlockCache:
         self.evictions = 0
         self._d = collections.OrderedDict()
         self._pinned = set()
+        self._lock = threading.Lock()
 
     def get(self, key):
-        e = self._d.get(key)
-        if e is None:
-            self.misses += 1
-            if OBS.enabled:
-                OBS.inc("store.cache.misses")
-            return None
-        self._d.move_to_end(key)
-        self.hits += 1
+        with self._lock:
+            e = self._d.get(key)
+            if e is None:
+                self.misses += 1
+            else:
+                self._d.move_to_end(key)
+                self.hits += 1
         if OBS.enabled:
-            OBS.inc("store.cache.hits")
+            OBS.inc("store.cache.misses" if e is None else "store.cache.hits")
         return e
 
     def put(self, key, entry):
-        old = self._d.pop(key, None)
-        if old is not None:
-            self.nbytes -= old[_E_NBYTES]
-        self._d[key] = entry
-        self.nbytes += entry[_E_NBYTES]
-        self._evict()
+        with self._lock:
+            old = self._d.pop(key, None)
+            if old is not None:
+                self.nbytes -= old[_E_NBYTES]
+            self._d[key] = entry
+            self.nbytes += entry[_E_NBYTES]
+            self._evict()
+            nbytes = self.nbytes
         if OBS.enabled:
-            OBS.gauge("store.cache.nbytes", self.nbytes)
+            OBS.gauge("store.cache.nbytes", nbytes)
 
     def grow(self, key, extra: int):
-        if key in self._d:
-            self._d[key][_E_NBYTES] += extra
-            self.nbytes += extra
-            self._evict()
+        with self._lock:
+            if key in self._d:
+                self._d[key][_E_NBYTES] += extra
+                self.nbytes += extra
+                self._evict()
 
     def pin(self, key) -> bool:
         """Exempt a resident entry from eviction; returns False on miss."""
-        if key not in self._d:
-            return False
-        self._pinned.add(key)
-        return True
+        with self._lock:
+            if key not in self._d:
+                return False
+            self._pinned.add(key)
+            return True
 
     def unpin(self, key):
-        self._pinned.discard(key)
+        with self._lock:
+            self._pinned.discard(key)
 
     def invalidate(self, sid: str):
-        for key in [k for k in self._d if k[0] == sid]:
-            self.nbytes -= self._d.pop(key)[_E_NBYTES]
-            self._pinned.discard(key)
+        with self._lock:
+            for key in [k for k in self._d if k[0] == sid]:
+                self.nbytes -= self._d.pop(key)[_E_NBYTES]
+                self._pinned.discard(key)
 
     def drop(self, key):
         """Invalidate one block entry (streamed per-append invalidation)."""
-        e = self._d.pop(key, None)
-        if e is not None:
-            self.nbytes -= e[_E_NBYTES]
-            self._pinned.discard(key)
+        with self._lock:
+            e = self._d.pop(key, None)
+            if e is not None:
+                self.nbytes -= e[_E_NBYTES]
+                self._pinned.discard(key)
 
     def clear(self):
-        self._d.clear()
-        self._pinned.clear()
-        self.nbytes = 0
+        with self._lock:
+            self._d.clear()
+            self._pinned.clear()
+            self.nbytes = 0
 
     def _evict(self):
+        """Evict down to the budget (the caller holds the lock)."""
         ev = 0
         while self.nbytes > self.budget and self._d:
             if self._pinned:
@@ -238,10 +258,11 @@ class BlockCache:
             OBS.inc("store.cache.evictions", ev)
 
     def stats(self) -> dict:
-        return dict(hits=self.hits, misses=self.misses,
-                    evictions=self.evictions, entries=len(self._d),
-                    pinned=len(self._pinned),
-                    nbytes=self.nbytes, budget=self.budget)
+        with self._lock:
+            return dict(hits=self.hits, misses=self.misses,
+                        evictions=self.evictions, entries=len(self._d),
+                        pinned=len(self._pinned),
+                        nbytes=self.nbytes, budget=self.budget)
 
 
 class CameoStore:
@@ -285,6 +306,7 @@ class CameoStore:
                             stored_nbytes=0, raw_nbytes=0)
         self._cache = BlockCache(cache_bytes)  # (sid, bi) -> decoded entry
         self._metas: Dict[tuple, "BlockMeta"] = {}  # header-only cache
+        self._io = threading.RLock()  # the file handle and the mmap view
         self._streams: Dict[str, "StreamSession"] = {}  # open ingest streams
         self._writable = mode in ("w", "a")
         self._footer_dirty = False   # a footer sits at EOF; truncate first
@@ -527,11 +549,12 @@ class CameoStore:
 
     def _append_body(self, body: bytes) -> int:
         """Write one length-prefixed block body at EOF; returns its offset."""
-        self._ensure_appendable()
-        off = self._f.seek(0, os.SEEK_END)
-        self._f.write(struct.pack("<I", len(body)))
-        self._f.write(body)
-        self._mm_stale = True   # the map no longer covers the new bytes
+        with self._io:
+            self._ensure_appendable()
+            off = self._f.seek(0, os.SEEK_END)
+            self._f.write(struct.pack("<I", len(body)))
+            self._f.write(body)
+            self._mm_stale = True   # the map no longer covers the new bytes
         if OBS.enabled:
             OBS.inc("store.write.blocks")
             OBS.inc("store.write.bytes", 4 + len(body))
@@ -548,10 +571,8 @@ class CameoStore:
         t["raw_nbytes"] += 8 * points
 
     def _write_footer(self):
-        self._ensure_appendable()
         for sid, sess in self._streams.items():
             self._series[sid]["stream_state"] = sess._stash()
-        off = self._f.seek(0, os.SEEK_END)
         cat = {"block_len": self.block_len, "value_codec": self.value_codec,
                "entropy": self.entropy, "series": self._series}
         # optional keys are written only when set, so stores that never see
@@ -566,12 +587,20 @@ class CameoStore:
         # two-phase publish: the footer body must be durable *before* the
         # tail marker that makes readers trust it — a crash between the
         # barriers leaves a torn tail (recoverable), never a tail marker
-        # pointing at garbage
-        self._f.write(footer)
-        _wal.maybe_fsync(self._f)
-        self._f.write(_TAIL.pack(off, len(footer)))
-        self._f.write(_MAGICS[self.version])
-        _wal.maybe_fsync(self._f)
+        # pointing at garbage.  The I/O lock covers the writes, which move
+        # the file position; readers do not wait out the barriers.
+        with self._io:
+            self._ensure_appendable()
+            off = self._f.seek(0, os.SEEK_END)
+            self._f.write(footer)
+            self._f.flush()
+        self._fsync()
+        with self._io:
+            self._f.seek(off + len(footer))
+            self._f.write(_TAIL.pack(off, len(footer)))
+            self._f.write(_MAGICS[self.version])
+            self._f.flush()
+        self._fsync()
         self._footer_offset = off
         self._footer_len = len(footer)
         self._footer_dirty = True
@@ -580,6 +609,12 @@ class CameoStore:
             # never-resumed streams still need the journal to carry them
             carry = [r for recs in self._wal_pending.values() for r in recs]
             self._wal.checkpoint(self._wal_checkpoint(footer), carry)
+
+    def _fsync(self):
+        """The durability barrier of ``_wal.maybe_fsync`` on the store file,
+        whose buffered writes the caller has flushed under the I/O lock."""
+        if _wal.fsync_enabled():
+            os.fsync(self._f.fileno())
 
     def _load_footer(self):
         f = self._f
@@ -666,6 +701,15 @@ class CameoStore:
         univariate block bodies — v4 only adds the multivariate layout)."""
         return min(self.version, 3)
 
+    def _forget(self, sid: str) -> None:
+        """Drop ``sid``'s decoded blocks and block headers from the caches.
+        Reader threads fill the header cache without a lock, so its keys
+        are listed in one call before any is dropped (never iterated while
+        it grows)."""
+        self._cache.invalidate(sid)
+        for key in [k for k in list(self._metas) if k[0] == sid]:
+            self._metas.pop(key, None)
+
     def _mvar_body(self, kept_idx, kept_vals, *, t0: int, t1: int,
                    is_last: bool, dtype: str, cfg, x64, x_off: int = 0):
         """Encode one multivariate block: per-column canonical
@@ -687,15 +731,18 @@ class CameoStore:
             eps=cfg.eps, resid=resid, value_codec=self.value_codec,
             entropy=self.entropy)
 
-    def _append_multivariate(self, sid: str, res, cfg, X=None) -> dict:
+    def _append_multivariate(self, sid: str, res, cfg, X,
+                             with_resid: bool) -> dict:
         """Write one multivariate series (see ``append_series``)."""
         kept = np.asarray(res.kept)
         xr = np.asarray(res.xr)
         n, C = xr.shape
         self._check_mvar_writable()
         kept_idx = np.nonzero(kept)[0].astype(np.int64)
-        kept_vals = np.ascontiguousarray(xr[kept_idx])
-        x64 = None if X is None else np.asarray(X, np.float64)[:n]
+        kept_vals = np.ascontiguousarray(
+            (xr if X is None else np.asarray(X))[kept_idx], xr.dtype)
+        x64 = (np.asarray(X, np.float64)[:n]
+               if X is not None and with_resid else None)
         bounds = plan_block_bounds(kept_idx, self.block_len, cfg.lags)
         devs = np.asarray(getattr(res, "deviations",
                                   np.full(C, float(res.deviation))),
@@ -729,21 +776,23 @@ class CameoStore:
         self._series[sid] = entry
         self._bump_totals(series=1, points=n * C,
                           n_kept=entry["n_kept"] * C, stored=nbytes)
-        self._cache.invalidate(sid)
-        for key in [k for k in self._metas if k[0] == sid]:
-            del self._metas[key]
+        self._forget(sid)
         return entry
 
-    def append_series(self, sid: str, res, cfg, x=None) -> dict:
+    def append_series(self, sid: str, res, cfg, x=None,
+                      with_resid: bool = True) -> dict:
         """Write one compressed series.
 
         ``res`` is a ``CompressResult`` (anything with ``.kept`` / ``.xr``
         works), ``cfg`` the ``CameoConfig`` it was produced under, and ``x``
-        optionally the *original* series — when given, per-block residual
-        moments are stored and pushdown value aggregates carry deterministic
-        error bounds **vs the original** (otherwise vs the reconstruction).
-        Returns the catalog entry (byte sizes, per-block extents).  Any
-        cached decoded blocks for ``sid`` are invalidated.
+        optionally the *original* series.  When ``x`` is given, its points
+        are what is stored at the kept positions — on a TPU ``res.xr`` is
+        the device's emulated float64, which can round the low bits of a
+        kept point — and, with ``with_resid``, per-block residual moments
+        are stored too, so that pushdown value aggregates carry
+        deterministic error bounds **vs the original** (otherwise vs the
+        reconstruction).  Returns the catalog entry (byte sizes, per-block
+        extents).  Any cached decoded blocks for ``sid`` are invalidated.
 
         The stored reconstruction is the *canonical* one-shot interpolation
         of the kept points (the paper's §4.1 decompression), computed here
@@ -758,13 +807,23 @@ class CameoStore:
             raise IOError("store opened read-only")
         if sid in self._series:
             raise ValueError(f"series {sid!r} already stored")
+        with span("store.append_series"):
+            if np.ndim(res.xr) == 2:
+                return self._append_multivariate(sid, res, cfg, x,
+                                                 with_resid)
+            return self._append_univariate(sid, res, cfg, x, with_resid)
+
+    def _append_univariate(self, sid: str, res, cfg, x,
+                           with_resid: bool) -> dict:
+        """Write one univariate series (see ``append_series``)."""
         kept = np.asarray(res.kept)
         xr = np.asarray(res.xr)
-        if xr.ndim == 2:
-            return self._append_multivariate(sid, res, cfg, X=x)
         n = int(kept.shape[0])
         kept_idx = np.nonzero(kept)[0].astype(np.int64)
-        x64 = None if x is None else np.asarray(x, np.float64)[:n]
+        kept_vals = np.asarray(
+            (xr if x is None else np.asarray(x))[kept_idx], xr.dtype)
+        x64 = (np.asarray(x, np.float64)[:n]
+               if x is not None and with_resid else None)
         bounds = plan_block_bounds(kept_idx, self.block_len, cfg.lags)
 
         blocks: List[dict] = []
@@ -774,7 +833,7 @@ class CameoStore:
             is_last = bi == len(bounds) - 2
             o1 = t1 + 1 if is_last else t1
             sel = (kept_idx >= t0) & (kept_idx <= t1)
-            bidx, bvals = kept_idx[sel], xr[kept_idx[sel]]
+            bidx, bvals = kept_idx[sel], kept_vals[sel]
             owned_xr = reconstruct_block(
                 bidx - t0, bvals, t1 - t0 + 1, str(xr.dtype))[:o1 - t0]
             body, binfo = build_block(
@@ -802,9 +861,7 @@ class CameoStore:
         self._series[sid] = entry
         self._bump_totals(series=1, points=n, n_kept=entry["n_kept"],
                           stored=nbytes)
-        self._cache.invalidate(sid)
-        for key in [k for k in self._metas if k[0] == sid]:
-            del self._metas[key]
+        self._forget(sid)
         return entry
 
     def open_stream(self, sid: str, cfg, *, dtype: str = None,
@@ -922,20 +979,21 @@ class CameoStore:
         return _codec.entropy_unwrap(bytes(raw), wrap)
 
     def _read_body(self, blk: dict) -> bytes:
-        mm = self._mmap()
-        if mm is not None:
-            off = blk["offset"]
-            blen, = struct.unpack_from("<I", mm, off)
-            if OBS.enabled:
-                OBS.inc("store.read.mmap_bytes", 4 + blen)
-                OBS.inc("store.read.blocks_fetched")
-            return self._finish_body(blk, mm[off + 4:off + 4 + blen])
-        self._f.seek(blk["offset"])
-        blen, = struct.unpack("<I", self._f.read(4))
+        off = blk["offset"]
+        with self._io:
+            mm = self._mmap()
+            if mm is not None:
+                blen, = struct.unpack_from("<I", mm, off)
+                raw = mm[off + 4:off + 4 + blen]
+            else:
+                self._f.seek(off)
+                blen, = struct.unpack("<I", self._f.read(4))
+                raw = self._f.read(blen)
         if OBS.enabled:
-            OBS.inc("store.read.pread_bytes", 4 + blen)
+            OBS.inc("store.read.pread_bytes" if mm is None
+                    else "store.read.mmap_bytes", 4 + blen)
             OBS.inc("store.read.blocks_fetched")
-        return self._finish_body(blk, self._f.read(blen))
+        return self._finish_body(blk, raw)
 
     def _read_bodies(self, blks: List[dict]) -> List[bytes]:
         """One body per catalog entry; blocks that sit contiguously in the
@@ -943,18 +1001,24 @@ class CameoStore:
         block (multi-block windows of an uninterleaved series are one IO).
         With an mmap attached every body is a page-cache slice — no
         syscalls at all, so no coalescing is needed."""
-        if self._mmap() is not None:
+        with self._io:
+            mapped = self._mmap() is not None
+        if mapped:
             return [self._read_body(b) for b in blks]
-        out: List[bytes] = []
-        i = 0
-        while i < len(blks):
-            j = i
-            end = blks[j]["offset"] + 4 + blks[j]["nbytes"]
-            while j + 1 < len(blks) and blks[j + 1]["offset"] == end:
-                j += 1
+        runs = []                       # (first, last, bytes) per run
+        with self._io:
+            i = 0
+            while i < len(blks):
+                j = i
                 end = blks[j]["offset"] + 4 + blks[j]["nbytes"]
-            self._f.seek(blks[i]["offset"])
-            buf = self._f.read(end - blks[i]["offset"])
+                while j + 1 < len(blks) and blks[j + 1]["offset"] == end:
+                    j += 1
+                    end = blks[j]["offset"] + 4 + blks[j]["nbytes"]
+                self._f.seek(blks[i]["offset"])
+                runs.append((i, j, self._f.read(end - blks[i]["offset"])))
+                i = j + 1
+        out: List[bytes] = []
+        for i, j, buf in runs:
             if OBS.enabled:
                 OBS.inc("store.read.coalesced_runs")
                 OBS.inc("store.read.pread_bytes", len(buf))
@@ -965,7 +1029,6 @@ class CameoStore:
                 out.append(self._finish_body(blks[k],
                                              buf[pos + 4:pos + 4 + blen]))
                 pos += 4 + blen
-            i = j + 1
         return out
 
     def channels(self, sid: str) -> int:
@@ -1477,7 +1540,9 @@ class StreamSession:
             self._emit(j, t1, is_last=(bi == len(bounds) - 2))
         # the finalized blocks are durable before the catalog entry that
         # publishes them can be (CAMEO_FSYNC=0 keeps just the write order)
-        _wal.maybe_fsync(self._store._f)
+        with self._store._io:
+            self._store._f.flush()
+        self._store._fsync()
         e = self._entry
         e["n"] = last + 1
         e["n_kept"] = self._total_kept
